@@ -93,4 +93,6 @@ def _fit(rng):
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

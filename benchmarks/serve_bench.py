@@ -166,4 +166,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

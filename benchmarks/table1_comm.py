@@ -26,7 +26,6 @@ NPP = 256
 
 
 def lower_algo(algorithm):
-    from repro.runtime.compat import shard_map
     mesh = default_mesh(P_DEV)
     fn = _algorithm_fn(algorithm)
 
@@ -37,8 +36,9 @@ def lower_algo(algorithm):
 
     keys = jax.ShapeDtypeStruct((P_DEV, NPP), jax.numpy.uint32)
     with mesh:
-        c = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("sort"),),
-                              out_specs=(P("sort"), P("sort")))
+        c = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("sort"),),
+                                  out_specs=(P("sort"), P("sort")),
+                                  check_vma=False)
                     ).lower(keys).compile()
     return hlo_cost.analyze(c.as_text())
 
@@ -77,4 +77,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -1,24 +1,27 @@
 """Quickstart: robust distributed sorting with repro.core.psort.
 
-Sorts every paper input instance with the auto-selected algorithm on 8
-emulated TPU devices and prints the selection + balance.
+Sorts every paper input instance with the auto-selected algorithm over
+every device JAX sees — the chips of a TPU host, or 8 emulated devices on a
+CPU — and prints the selection + balance.
 
   PYTHONPATH=src python examples/quickstart.py
 """
 import os
 
+# only the host (CPU) platform reads this; a TPU keeps its real chip count
 if "XLA_FLAGS" not in os.environ:
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
+import jax                                          # noqa: E402
 import numpy as np                                  # noqa: E402
 
 from repro.core import SortConfig, psort, select_algorithm  # noqa: E402
 from repro.data.distributions import INSTANCES, generate_instance  # noqa: E402
 
-P = 8
-
 
 def main():
+    P = jax.device_count()
+    print(f"shard_map over {P} {jax.devices()[0].platform} device(s)")
     print(f"{'instance':14s} {'n':>7s} {'algorithm':10s} {'sorted':6s} "
           f"{'balance':7s} {'overflow'}")
     for inst in sorted(INSTANCES):

@@ -43,8 +43,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.runtime.compat import shard_map
-
 from . import comm, selection
 from .types import (SortShard, key_to_uint, local_kernels, make_shard,
                     pad_value, uint_to_key)
@@ -251,9 +249,10 @@ def _psort_jit(keys2d, counts, mesh, cfg, axis_name, p, algorithm, capacity,
         k, i, c, o = body(keys_blk[0], count_blk[0])
         return k[None], i[None], c[None], o[None]
 
-    out = shard_map(blk, mesh=mesh,
-                    in_specs=(P(axis_name), P(axis_name)),
-                    out_specs=(P(axis_name),) * 4)(keys2d, counts)
+    out = jax.shard_map(blk, mesh=mesh,
+                        in_specs=(P(axis_name), P(axis_name)),
+                        out_specs=(P(axis_name),) * 4,
+                        check_vma=False)(keys2d, counts)
     return out
 
 
@@ -279,10 +278,11 @@ def _psort2_jit(keys3d, counts, mesh, cfg, axis_name, data_axis, p, algorithm,
         k, i, c, o = body(keys_blk[0, 0], count_blk[0, 0])
         return (k[None, None], i[None, None], c[None, None], o[None, None])
 
-    out = shard_map(blk, mesh=mesh,
-                    in_specs=(P(data_axis, axis_name),
-                              P(data_axis, axis_name)),
-                    out_specs=(P(data_axis, axis_name),) * 4)(keys3d, counts)
+    out = jax.shard_map(blk, mesh=mesh,
+                        in_specs=(P(data_axis, axis_name),
+                                  P(data_axis, axis_name)),
+                        out_specs=(P(data_axis, axis_name),) * 4,
+                        check_vma=False)(keys3d, counts)
     return out
 
 
@@ -320,9 +320,10 @@ def _psort_nested_jit(keys_nd, counts, mesh, cfg, axis_name, data_axis, axes,
         dims = tuple(range(nlead))
         return tuple(jnp.expand_dims(v, dims) for v in (k, i, c, o))
 
-    out = shard_map(blk, mesh=mesh,
-                    in_specs=(P(*names), P(*names)),
-                    out_specs=(P(*names),) * 4)(keys_nd, counts)
+    out = jax.shard_map(blk, mesh=mesh,
+                        in_specs=(P(*names), P(*names)),
+                        out_specs=(P(*names),) * 4,
+                        check_vma=False)(keys_nd, counts)
     return out
 
 

@@ -159,10 +159,8 @@ def _classify_planes(k, t, s_keys, s_ties, nb: int, *, use_kernel: bool):
     C = k.shape[0]
     if (use_kernel and k.dtype == jnp.uint32 and t.dtype == jnp.uint32
             and C >= kway_ops._BLOCK and nb >= 2):
-        interpret = jax.default_backend() != "tpu"
         bucket, _ = kway_ops.kway_classify(k, t, s_keys, s_ties,
-                                           n_buckets=nb, interpret=interpret,
-                                           use_kernel=True)
+                                           n_buckets=nb, use_kernel=True)
         return bucket.astype(jnp.int32)
     if s_keys.shape[0] == 0:
         return jnp.zeros((C,), jnp.int32)

@@ -56,8 +56,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.compat import shard_map
-
 from . import comm
 from .median import butterfly_rank_window
 from .rams import quantile_splitters
@@ -316,9 +314,9 @@ def _counts_shard_jit(keys2d, counts, cands, mesh, axis, p):
         out = body(k[0], c[0], q[0])
         return tuple(o[None] for o in out)
 
-    return shard_map(blk, mesh=mesh, in_specs=(P(axis),) * 3,
-                     out_specs=(P(axis),) * 2)(keys2d, counts,
-                                               _tile(cands, p))
+    return jax.shard_map(blk, mesh=mesh, in_specs=(P(axis),) * 3,
+                         out_specs=(P(axis),) * 2,
+                         check_vma=False)(keys2d, counts, _tile(cands, p))
 
 
 @partial(jax.jit, static_argnames=("axis", "p", "bits", "use_window"))
@@ -337,10 +335,10 @@ def _select_shard_jit(keys2d, counts, ranks, fracs, mesh, axis, p, bits,
         out = body(k[0], c[0], r[0], f[0])
         return tuple(o[None] for o in out)
 
-    return shard_map(blk, mesh=mesh, in_specs=(P(axis),) * 4,
-                     out_specs=(P(axis),) * 3)(keys2d, counts,
-                                               _tile(ranks, p),
-                                               _tile(fracs, p))
+    return jax.shard_map(blk, mesh=mesh, in_specs=(P(axis),) * 4,
+                         out_specs=(P(axis),) * 3,
+                         check_vma=False)(keys2d, counts, _tile(ranks, p),
+                                          _tile(fracs, p))
 
 
 @partial(jax.jit, static_argnames=("axis", "p", "bits", "use_window",
@@ -362,10 +360,10 @@ def _topk_shard_jit(keys2d, counts, ranks, fracs, mesh, axis, p, bits,
         out = body(k[0], c[0], r[0], f[0])
         return tuple(o[None] for o in out)
 
-    return shard_map(blk, mesh=mesh, in_specs=(P(axis),) * 4,
-                     out_specs=(P(axis),) * 5)(keys2d, counts,
-                                               _tile(ranks, p),
-                                               _tile(fracs, p))
+    return jax.shard_map(blk, mesh=mesh, in_specs=(P(axis),) * 4,
+                         out_specs=(P(axis),) * 5,
+                         check_vma=False)(keys2d, counts, _tile(ranks, p),
+                                          _tile(fracs, p))
 
 
 def _mesh_for(data: ResidentData, mesh, axis: str):

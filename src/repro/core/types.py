@@ -138,11 +138,13 @@ def make_shard(keys: jax.Array, count=None, capacity: Optional[int] = None,
 # Local-phase kernel policy.  Two Pallas kernels cover the local hot spots:
 # the bitonic local sort (kernels/bitonic) and the fused partition-into-
 # buckets classifier (kernels/partition).  On a TPU backend both default ON
-# — the local phase is the speed floor of every algorithm here; everywhere
-# else (CPU/sim CI) they default OFF because interpret-mode execution is
-# slow, and the jnp paths are the bitwise oracle the kernels are diffed
-# against.  The ``REPRO_LOCAL_KERNELS`` environment variable (read at trace
-# time, so ``monkeypatch.setenv`` works) overrides the default:
+# and compile through Mosaic (``repro.kernels.interpret_mode``); whether
+# either beats the jnp path on the chip has not been measured yet.
+# Everywhere else (CPU/sim CI) they default OFF because there they run in
+# the Pallas interpreter, which is slow; the jnp paths are the bitwise
+# oracle the kernels are diffed against.  The ``REPRO_LOCAL_KERNELS``
+# environment variable (read at trace time, so ``monkeypatch.setenv``
+# works) overrides the default:
 #
 #   REPRO_LOCAL_KERNELS=all | 1 | on      both kernels
 #   REPRO_LOCAL_KERNELS=none | 0 | off    neither

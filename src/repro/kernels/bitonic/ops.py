@@ -2,11 +2,15 @@
 
 ``local_sort_fast(keys, vals)`` sorts **arbitrary sizes**: non-power-of-two
 inputs are padded up to the next power of two with ``pad_val`` and sliced
-back after the sort, so real shard capacities take the kernel path.  Tiles
-≤ ``MAX_TILE`` are sorted by one kernel launch; larger inputs are sorted
-tile-wise and combined with log(n/MAX_TILE) merge-kernel passes.  Only
-4-byte words lower to the TPU kernel — 64-bit keys fall back to the jnp
-reference.
+back after the sort, so real shard capacities take the kernel path.  Inputs
+of at most ``MAX_TILE`` words are sorted by one kernel block.  Larger ones
+are a full bitonic sort split by distance: one :func:`bitonic.sort_blocks`
+launch sorts every ``MAX_TILE`` block; then each of the log2(n/MAX_TILE)
+merge rounds is one flip step and the half-cleaner steps at distances of a
+block or more as XLA elementwise passes, followed by one
+:func:`bitonic.clean_blocks` launch for the distances inside a block.  So
+an n-word sort is 1 + log2(n/MAX_TILE) kernel launches.  Only 4-byte words
+take the kernel — 64-bit keys fall back to the jnp reference.
 
 Padding caveat (shared with the power-of-two path, whose capacity padding
 has the same property): the bitonic network is *not stable*.  ``pad_val``
@@ -17,9 +21,8 @@ element's payload.  Callers that sort max-representable keys with payloads
 should pass a ``pad_val`` known to be absent from the data, or use the
 stable jnp path (``use_kernel=False``).
 
-The kernels execute in ``interpret=True`` mode on CPU (this container);
-on TPU the same ``pallas_call`` lowers to Mosaic with the BlockSpecs
-declared in bitonic.py.
+On a TPU the kernels compile through Mosaic; elsewhere they run in the
+Pallas interpreter (:func:`repro.kernels.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -30,10 +33,6 @@ from . import bitonic
 from .bitonic import LANES
 
 MAX_TILE = 1 << 14          # 16Ki elements/tile: 64 KiB keys + 64 KiB vals
-
-
-def _is_pow2(n: int) -> bool:
-    return n > 0 and (n & (n - 1)) == 0
 
 
 def _next_pow2(n: int) -> int:
@@ -53,7 +52,7 @@ def _default_pad(dtype):
     return jnp.iinfo(dt).max
 
 
-def local_sort_fast(keys: jax.Array, vals=None, *, interpret: bool = True,
+def local_sort_fast(keys: jax.Array, vals=None, *, interpret=None,
                     use_kernel: bool = True, pad_val=None):
     """Sort keys (u32/i32/f32) ascending, carrying an optional u32 payload.
 
@@ -72,35 +71,28 @@ def local_sort_fast(keys: jax.Array, vals=None, *, interpret: bool = True,
         if vals is not None:
             vals = jnp.concatenate(
                 [vals, jnp.zeros((m - n,), vals.dtype)])
-        if vals is None:
-            return _sort_pow2(keys, None, interpret)[:n]
-        ks, vs = _sort_pow2(keys, vals, interpret)
-        return ks[:n], vs[:n]
-    return _sort_pow2(keys, vals, interpret)
+    out = _sort_pow2(keys, vals, interpret)
+    if vals is None:
+        return out[:n]
+    return out[0][:n], out[1][:n]
 
 
 def _sort_pow2(keys, vals, interpret):
     n = keys.shape[0]
-    if n <= MAX_TILE:
-        return bitonic.sort_tile(keys, vals, interpret=interpret)
-    # tile-wise sort + log2(n/tile) merge passes
-    t = MAX_TILE
-    if vals is None:
-        tiles = [bitonic.sort_tile(keys[i:i + t], interpret=interpret)
-                 for i in range(0, n, t)]
-        while len(tiles) > 1:
-            tiles = [bitonic.merge_tiles(tiles[i], tiles[i + 1],
-                                         interpret=interpret)
-                     for i in range(0, len(tiles), 2)]
-        return tiles[0]
-    pairs = [bitonic.sort_tile(keys[i:i + t], vals[i:i + t],
-                               interpret=interpret) for i in range(0, n, t)]
-    while len(pairs) > 1:
-        pairs = [bitonic.merge_tiles(pairs[i][0], pairs[i + 1][0],
-                                     pairs[i][1], pairs[i + 1][1],
-                                     interpret=interpret)
-                 for i in range(0, len(pairs), 2)]
-    return pairs[0]
+    t = min(n, MAX_TILE)
+    out = bitonic.sort_blocks(keys, vals, block=t, interpret=interpret)
+    keys, vals = (out, None) if vals is None else out
+    run = t
+    while run < n:                  # merge ascending runs pairwise
+        keys, vals = bitonic.merge_step(keys, vals, run, flip=True)
+        dist = run // 2
+        while dist >= t:
+            keys, vals = bitonic.merge_step(keys, vals, dist)
+            dist //= 2
+        out = bitonic.clean_blocks(keys, vals, block=t, interpret=interpret)
+        keys, vals = (out, None) if vals is None else out
+        run *= 2
+    return keys if vals is None else (keys, vals)
 
 
 def bitonic_ref(keys, vals=None):

@@ -13,10 +13,10 @@ _BLOCK = BLOCK_R * LANES
 
 
 def kway_classify(keys, ties, s_keys, s_ties, *, n_buckets: int,
-                  interpret: bool = True, use_kernel: bool = True):
+                  interpret=None, use_kernel: bool = True):
     """Classify u32 (key, tie) pairs against (NB-1,) lex splitters."""
     C = keys.shape[0]
-    if not use_kernel or C < _BLOCK:
+    if not use_kernel or C < _BLOCK or s_keys.shape[0] == 0:
         from . import ref
         return ref.kway_classify_ref(keys, ties, s_keys, s_ties,
                                      n_buckets=n_buckets)

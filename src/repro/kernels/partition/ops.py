@@ -5,11 +5,11 @@
 Pallas tile kernel (partition.py) or the jnp reference (ref.py) — bitwise
 identical by tests/test_partition.py — and hides the tiling:
 
-  * the shard is padded to a lane multiple and cut into VMEM-sized tiles
-    (tile rows shrink as the bucket count grows: the kernel's working set
-    is the (R, 128, nb+1) one-hot);
-  * the running histogram threads through the launches, so ranks are
-    global over the whole shard exactly like the reference's one argsort.
+  * the shard is padded to whole tiles of ``_tile_rows(nb)`` rows (one
+    tile of whole (8, 128) vregs when the shard is smaller);
+  * a ``lax.scan`` over the tiles threads the running histogram through
+    the launches, so ranks are global over the whole shard exactly like
+    the reference's one argsort.
 
 Kernel-vs-ref selection: an explicit ``use_kernel`` wins; ``None`` defers
 to :func:`repro.core.types.local_kernels` (the ``REPRO_LOCAL_KERNELS``
@@ -19,13 +19,18 @@ every case; the kernel additionally requires uint32 planes, 2 ≤ nb ≤
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .partition import LANES, partition_tile
 from .ref import partition_ref
 
-MAX_BUCKETS = 512            # beyond this the one-hot tile no longer fits
-_VMEM_WORDS = 1 << 20        # ≈4 MiB budget for one (R, 128, nb+1) i32
+# Tile sizes are those of the (R, 128, nb+1) one-hot formulation the kernel
+# replaced (a ≈4 MiB budget for that one-hot).  The kernel now holds a few
+# (R, 128) planes at any nb, so the rows need not shrink with nb; which
+# size is fastest on the chip is not measured.
+MAX_BUCKETS = 512
+_VMEM_WORDS = 1 << 20
 
 
 def _tile_rows(n_buckets: int) -> int:
@@ -35,7 +40,7 @@ def _tile_rows(n_buckets: int) -> int:
 
 def partition_buckets(keys, ties, s_keys, s_ties, *, n_buckets: int,
                       count=None, inclusive: bool = True,
-                      want_pos: bool = True, interpret: bool = True,
+                      want_pos: bool = True, interpret=None,
                       use_kernel=None):
     """Fused classify + rank + histogram over a locally-sorted shard.
 
@@ -55,30 +60,30 @@ def partition_buckets(keys, ties, s_keys, s_ties, *, n_buckets: int,
                              want_pos=want_pos)
 
     cnt = jnp.asarray(C if count is None else count, jnp.int32)
-    pad = (-C) % LANES
-    if pad:                     # pad rows classify as trash (flat ≥ nvalid)
+    rows = -(-C // LANES)
+    R = _tile_rows(n_buckets)
+    if rows <= R:               # one tile, rounded up to whole (8, 128) vregs
+        R = -(-rows // 8) * 8
+    n_tiles = -(-rows // R)
+    tile = R * LANES
+    pad = n_tiles * tile - C
+    if pad:                     # pads classify as trash (flat ≥ nvalid)
         fill = jnp.full((pad,), 0xFFFFFFFF, jnp.uint32)
         keys = jnp.concatenate([keys, fill])
         ties = jnp.concatenate([ties, fill])
-    tile = _tile_rows(n_buckets) * LANES
-    hist = jnp.zeros((1, n_buckets + 1), jnp.int32)
-    buckets, poss = [], []
-    off = 0
-    total = C + pad
-    while off < total:
-        step = min(tile, total - off)
-        R = step // LANES
-        nv = jnp.clip(cnt - off, 0, step).reshape(1, 1)
-        b, q, hist = partition_tile(
-            keys[off:off + step].reshape(R, LANES),
-            ties[off:off + step].reshape(R, LANES),
-            s_keys, s_ties, hist, nv,
-            n_buckets=n_buckets, inclusive=inclusive, interpret=interpret)
-        buckets.append(b.reshape(step))
-        poss.append(q.reshape(step))
-        off += step
-    bucket = jnp.concatenate(buckets)[:C] if len(buckets) > 1 \
-        else buckets[0][:C]
-    pos = (jnp.concatenate(poss)[:C] if len(poss) > 1 else poss[0][:C]) \
-        if want_pos else None
+
+    def step(hist, xs):         # one launch per tile, histogram threaded
+        k, t, off = xs
+        nv = jnp.clip(cnt - off, 0, tile).reshape(1, 1)
+        b, q, hist = partition_tile(k, t, s_keys, s_ties, hist, nv,
+                                    n_buckets=n_buckets, inclusive=inclusive,
+                                    interpret=interpret)
+        return hist, (b, q)
+
+    hist, (bucket, pos) = jax.lax.scan(
+        step, jnp.zeros((1, n_buckets + 1), jnp.int32),
+        (keys.reshape(n_tiles, R, LANES), ties.reshape(n_tiles, R, LANES),
+         jnp.arange(n_tiles, dtype=jnp.int32) * tile))
+    bucket = bucket.reshape(-1)[:C]
+    pos = pos.reshape(-1)[:C] if want_pos else None
     return bucket, pos, hist[0, :n_buckets]

@@ -1,26 +1,36 @@
 """Pallas TPU kernel: fused splitter classify + histogram + in-bucket rank.
 
-One VMEM-resident pass over a (R, 128) tile does everything the all_to_all
+One VMEM-resident pass over an (R, 128) tile does everything the all_to_all
 routing needs (the IPS⁴o block-partition shape, arXiv 2009.13569, mapped
 onto the VPU):
 
   * classify: branchless SSSS ``#splitters ≤ elem`` as a lexicographic
-    (key, tie) broadcast-compare against the S = nb-1 splitter planes —
-    no u64 composites materialize, the two u32 planes compare directly;
-  * histogram + stable rank: an (R, 128, nb+1) one-hot is reduced twice —
-    ``cumsum`` along lanes + a row-prefix along sublanes give each element
-    its stable in-bucket rank in flat (row-major) order, and the column
-    sums give the tile histogram.  Elements at flat index ≥ ``nvalid``
-    (shard padding) land in the **trash bucket** nb.
+    (key, tie) compare of the tile against each of the S = nb-1 splitters,
+    read as scalars from SMEM — no u64 composites materialize, the two u32
+    planes compare directly;
+  * histogram + stable rank: for each bucket b, the 0/1 plane ``bucket ==
+    b`` gets an inclusive prefix sum along lanes and an exclusive one over
+    the row totals along sublanes, both as log-step roll-and-add scans.
+    Their sum is each element's stable in-bucket rank in flat (row-major)
+    order, and the last row's total is the tile's count of b.  Elements at
+    flat index ≥ ``nvalid`` (shard padding) land in the **trash bucket**
+    nb.
 
-The kernel is deliberately ``grid=(1,)`` whole-tile — like kernels/bitonic,
-and unlike kernels/kway's ``program_id``-based grid — so it stays correct
+Work is O(R·128·nb) per tile, like the one-hot formulation it replaces,
+but nothing wider than the tile is ever live.
+
+The kernel is deliberately ``grid=(1,)`` whole-tile — kernels/bitonic's
+grid steps are likewise independent, unlike kernels/kway's
+``program_id``-based histogram accumulation — so it stays correct
 under vmap batching (the sim backend wraps every PE body in one vmap; jax
 prepends batch dims to the pallas grid, which breaks program_id-relative
-offsets but leaves whole-tile launches untouched).  Host code in ops.py
-chains tiles by threading the running histogram through successive
-launches; ``prev_hist[bucket] + rank_in_tile`` is then the global stable
-send position.
+offsets but leaves whole-tile launches untouched).  ops.py chains tiles by
+threading the running histogram through a ``lax.scan`` of launches;
+``prev_hist[bucket] + rank_in_tile`` is then the global stable send
+position.
+
+On a TPU the kernel compiles through Mosaic; elsewhere it runs in the
+Pallas interpreter (:func:`repro.kernels.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -28,68 +38,109 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 LANES = 128
+_ZERO = np.int32(0)          # int32 block index: a Python 0 is i64 under x64
+
+
+def _scan(x, axis: int, size: int):
+    """Inclusive prefix sum of ``x`` along ``axis`` (log-step roll-and-add)."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    s = 1
+    while s < size:
+        x = x + jnp.where(idx >= s, pltpu.roll(x, np.int32(s), axis), _ZERO)
+        s *= 2
+    return x
+
+
+def loop(n: int, body, init):
+    """``fori_loop(0, n, body, init)`` with an int32 counter: under
+    jax_enable_x64 fori_loop's own counter is i64, which Mosaic rejects."""
+    def step(carry, _):
+        i, x = carry
+        return (i + np.int32(1), body(i, x)), None
+    return jax.lax.scan(step, (np.int32(0), init), None, length=n)[0][1]
+
+
+def classify(k, t, sk_ref, st_ref, n_split: int, inclusive: bool = True):
+    """#splitters ≤ (k, t) (``inclusive``) or < (k, t), lexicographically,
+    for a tile of u32 planes against ``n_split`` splitters held as (1, S)
+    SMEM rows."""
+    def step(s, bucket):
+        sk, st = sk_ref[0, s], st_ref[0, s]
+        tie = (st <= t) if inclusive else (st < t)
+        return bucket + ((sk < k) | ((sk == k) & tie)).astype(jnp.int32)
+    return loop(n_split, step, jnp.zeros(k.shape, jnp.int32))
 
 
 def _partition_kernel(keys_ref, ties_ref, sk_ref, st_ref, ph_ref, nv_ref,
                       bucket_ref, pos_ref, hist_ref, *,
                       n_buckets: int, inclusive: bool):
     R = keys_ref.shape[0]
-    nbt = n_buckets + 1
-    k = keys_ref[...][..., None]                     # (R, 128, 1)
-    t = ties_ref[...][..., None]
-    sk = sk_ref[...][None, None, :]                  # (1, 1, S)
-    st = st_ref[...][None, None, :]
-    if inclusive:                                    # splitter ≤ element?
-        le = (sk < k) | ((sk == k) & (st <= t))
-    else:                                            # splitter < element?
-        le = (sk < k) | ((sk == k) & (st < t))
-    bucket = jnp.sum(le, axis=-1, dtype=jnp.int32)   # (R, 128)
-    r = jax.lax.broadcasted_iota(jnp.int32, (R, LANES), 0)
-    l = jax.lax.broadcasted_iota(jnp.int32, (R, LANES), 1)
-    flat = r * LANES + l
-    bucket = jnp.where(flat < nv_ref[0, 0], bucket, jnp.int32(n_buckets))
+    shape = (R, LANES)
+    bucket = classify(keys_ref[...], ties_ref[...], sk_ref, st_ref,
+                      n_buckets - 1, inclusive)
+    r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    l = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    bucket = jnp.where(r * LANES + l < nv_ref[0, 0], bucket,
+                       jnp.int32(n_buckets))
     bucket_ref[...] = bucket
 
-    mask = bucket[..., None] == jax.lax.broadcasted_iota(
-        jnp.int32, (R, LANES, nbt), 2)
-    onehot = mask.astype(jnp.int32)                  # (R, 128, nbt)
-    crow = jnp.cumsum(onehot, axis=1, dtype=jnp.int32)   # within-row, incl.
-    rowtot = jnp.sum(onehot, axis=1, dtype=jnp.int32)    # (R, nbt)
-    rows_before = jnp.cumsum(rowtot, axis=0, dtype=jnp.int32) - rowtot
-    prev = ph_ref[...]                               # (1, nbt) running hist
-    base = prev[0][None, None, :] + rows_before[:, None, :]
-    # select my bucket's column: rank = earlier rows + earlier-in-row + prev
-    pos_ref[...] = jnp.sum(jnp.where(mask, base + crow - jnp.int32(1),
-                                     jnp.int32(0)), axis=-1, dtype=jnp.int32)
-    hist_ref[...] = prev + jnp.sum(rowtot, axis=0, dtype=jnp.int32)[None, :]
+    prev = ph_ref[...]                               # (1, H) running hist
+    hlane = jax.lax.broadcasted_iota(jnp.int32, prev.shape, 1)
+
+    def rank(b, carry):
+        pos, hist = carry
+        mine = bucket == b
+        in_row = _scan(mine.astype(jnp.int32), 1, LANES)    # inclusive
+        row_tot = jnp.broadcast_to(in_row[:, LANES - 1:], shape)
+        upto_row = _scan(row_tot, 0, R)                      # inclusive
+        base = jnp.sum(jnp.where(hlane == b, prev, _ZERO), axis=1,
+                       keepdims=True, dtype=jnp.int32)       # (1, 1)
+        # rank = earlier tiles + earlier rows + earlier-in-row
+        rank_b = base + (upto_row - row_tot) + in_row - np.int32(1)
+        pos = jnp.where(mine, rank_b, pos)
+        hist = hist + jnp.where(hlane == b, upto_row[R - 1:, :1], _ZERO)
+        return pos, hist
+
+    pos, hist = loop(n_buckets + 1, rank,
+                     (jnp.zeros(shape, jnp.int32), prev))
+    pos_ref[...] = pos
+    hist_ref[...] = hist
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_buckets", "inclusive", "interpret"))
 def partition_tile(keys2, ties2, s_keys, s_ties, prev_hist, nvalid, *,
-                   n_buckets: int, inclusive: bool = True,
-                   interpret: bool = True):
+                   n_buckets: int, inclusive: bool = True, interpret=None):
     """Partition one (R, 128) tile.  ``prev_hist`` is the (1, nb+1) running
     histogram of earlier tiles (trash bucket included); ``nvalid`` is a
     (1, 1) int32 count of valid elements in this tile (flat order).
     Returns (bucket (R,128), pos (R,128), new_hist (1, nb+1))."""
     R = keys2.shape[0]
     nbt = n_buckets + 1
-    blk = pl.BlockSpec((R, LANES), lambda i: (i, 0))
-    svec = pl.BlockSpec((n_buckets - 1,), lambda i: (0,))
-    hblk = pl.BlockSpec((1, nbt), lambda i: (0, 0))
-    one = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    width = -(-nbt // LANES) * LANES                 # lane-aligned histogram
+    hist = jnp.pad(prev_hist, ((0, 0), (0, width - nbt)))
+    blk = pl.BlockSpec((R, LANES), lambda i: (i, _ZERO))
+    whole = lambda i: (_ZERO, _ZERO)                 # noqa: E731
+    hblk = pl.BlockSpec((1, width), whole)
+    sblk = pl.BlockSpec((1, n_buckets - 1), whole, memory_space=pltpu.SMEM)
+    one = pl.BlockSpec((1, 1), whole, memory_space=pltpu.SMEM)
     kern = functools.partial(_partition_kernel, n_buckets=n_buckets,
                              inclusive=inclusive)
-    return pl.pallas_call(
+    bucket, pos, hist = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((R, LANES), jnp.int32),
                    jax.ShapeDtypeStruct((R, LANES), jnp.int32),
-                   jax.ShapeDtypeStruct((1, nbt), jnp.int32)),
-        in_specs=[blk, blk, svec, svec, hblk, one],
+                   jax.ShapeDtypeStruct((1, width), jnp.int32)),
+        in_specs=[blk, blk, sblk, sblk, hblk, one],
         out_specs=(blk, blk, hblk),
-        grid=(1,), interpret=interpret)(keys2, ties2, s_keys, s_ties,
-                                        prev_hist, nvalid)
+        grid=(1,), interpret=interpret_mode(interpret),
+    )(keys2, ties2, s_keys.reshape(1, -1), s_ties.reshape(1, -1), hist,
+      nvalid)
+    return bucket, pos, hist[:, :nbt]

@@ -27,18 +27,24 @@ def make_production_mesh(*, multi_pod: bool = False):
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512")
     # jax.make_mesh consumes exactly prod(shape) devices; slice explicitly so
     # the single-pod mesh also works when 512 emulated devices exist.
-    return jax.make_mesh(shape, axes, devices=devs[:ndev])
+    return _make_mesh(shape, axes, devs[:ndev])
+
+
+def _make_mesh(shape, axes, devices):
+    # Auto axes: the models place activations with with_sharding_constraint,
+    # which the installed jax.make_mesh default (Explicit axes) rejects
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh_shape(shape: Sequence[int], axes: Sequence[str]):
     """Elastic mesh builder (checkpoint restore onto a different topology)."""
     ndev = int(np.prod(shape))
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         devices=jax.devices()[:ndev])
+    return _make_mesh(shape, axes, jax.devices()[:ndev])
 
 
 def make_sort_mesh(p: Optional[int] = None, axis: str = "sort"):
     """1-D mesh for the standalone sorting workloads (configs/sortbench)."""
     devs = jax.devices()
     p = p or len(devs)
-    return jax.make_mesh((p,), (axis,), devices=devs[:p])
+    return _make_mesh((p,), (axis,), devs[:p])

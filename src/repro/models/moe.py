@@ -207,18 +207,18 @@ def moe_ep_shardmap(x, p, cfg, mesh, *, data_axes, model_axis="model",
     sorts independently.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.runtime.compat import shard_map
 
     ep = mesh.shape[model_axis]
     body = _ep_dispatch_body(cfg, model_axis, ep, capacity_factor,
                              slot_factor)
 
     dp = P(data_axes, model_axis, None)
-    y, aux, drops = shard_map(
+    y, aux, drops = jax.shard_map(
         body, mesh=mesh,
         in_specs=(dp, P(), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None)),
         out_specs=(dp, P(model_axis), P(model_axis)),
+        check_vma=False,
     )(x, p["router"], p["up"], p["gate"], p["down"])
     return y, jnp.mean(aux)
 
@@ -275,7 +275,6 @@ def moe_tp_shardmap(x, p, cfg, mesh, *, data_axes,
     """
     from jax.sharding import PartitionSpec as P
     from repro.core import comm
-    from repro.runtime.compat import shard_map
 
     E, k = cfg.n_experts, cfg.top_k
     dp = P(data_axes, None, None)
@@ -287,11 +286,12 @@ def moe_tp_shardmap(x, p, cfg, mesh, *, data_axes,
         y = comm.psum(y, "model")
         return y, aux[None]
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(dp, P(), P(None, None, "model"), P(None, None, "model"),
                   P(None, "model", None)),
         out_specs=(dp, P("model")),
+        check_vma=False,
     )(x, p["router"], p["up"], p["gate"], p["down"])
     return y, jnp.mean(aux)
 

@@ -10,8 +10,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import types as ct
 from repro.core import hypercube as hc
 
-from repro.runtime.compat import shard_map
-
 PDEV = 8
 
 
@@ -23,8 +21,9 @@ def _run(body, *arrays, p=PDEV, out_specs=None):
     mesh = _mesh(p)
     nspec = tuple(P("sort") for _ in arrays)
     with mesh:
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=nspec,
-                                 out_specs=out_specs or P("sort")))(*arrays)
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=nspec,
+                                     out_specs=out_specs or P("sort"),
+                                     check_vma=False))(*arrays)
 
 
 def test_hc_exchange_is_involution():

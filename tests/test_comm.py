@@ -17,7 +17,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import comm
 from repro.core.api import SortConfig, _sort_body, trace_collectives
-from repro.runtime.compat import shard_map
 
 PP = 8
 CONTIG = [[0, 1, 2, 3], [4, 5, 6, 7]]
@@ -42,8 +41,8 @@ def _run_shard_map(fn, x):
         return jax.tree.map(lambda a: a[None], out)
 
     with mesh:
-        return jax.jit(shard_map(blk, mesh=mesh, in_specs=(P("pe"),),
-                                 out_specs=P("pe")))(x)
+        return jax.jit(jax.shard_map(blk, mesh=mesh, in_specs=(P("pe"),),
+                                     out_specs=P("pe"), check_vma=False))(x)
 
 
 def _check_all_backends(fn, x):

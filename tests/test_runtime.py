@@ -230,7 +230,6 @@ def test_grad_compression_error_feedback():
     """int8 compressed psum with error feedback: SGD on a quadratic must
     converge to the same optimum as exact gradients."""
     from repro.optim.grad_compress import compressed_psum
-    from repro.runtime.compat import shard_map
 
     p = 4
     devs = jax.devices()[:p]
@@ -247,10 +246,10 @@ def test_grad_compression_error_feedback():
     w = {"w": jnp.zeros((32,), jnp.float32)}
     err = {"w": jnp.zeros((p, 32), jnp.float32)}
     with mesh:
-        stepf = jax.jit(shard_map(
+        stepf = jax.jit(jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(P(), P("data"), P("data")),
-            out_specs=(P(), P("data"))))
+            out_specs=(P(), P("data")), check_vma=False))
         for _ in range(200):
             g, err = stepf(w, data, err)
             w = {"w": w["w"] - 0.05 * g}
@@ -263,7 +262,6 @@ def test_grad_compression_reduces_wire_bytes():
     than an f32 psum of the same gradient."""
     from repro.launch import hlo_cost
     from repro.optim.grad_compress import compressed_psum_mean
-    from repro.runtime.compat import shard_map
     p = 4
     mesh = Mesh(np.array(jax.devices()[:p]), ("data",))
     g = jnp.zeros((1 << 16,), jnp.float32)
@@ -277,8 +275,9 @@ def test_grad_compression_reduces_wire_bytes():
 
     def wire(fn):
         with mesh:
-            c = jax.jit(shard_map(fn, mesh=mesh, in_specs=(P(), P()),
-                                  out_specs=(P(), P()))).lower(g, e).compile()
+            c = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(P(), P()),
+                                      out_specs=(P(), P()),
+                                      check_vma=False)).lower(g, e).compile()
         a = hlo_cost.analyze(c.as_text())
         return sum(a["collective_bytes"].values())
 
@@ -315,7 +314,6 @@ def test_grad_compression_sim_matches_shard_map_bitwise():
     result bit for bit (the comm-layer contract of test_differential)."""
     from repro.core import comm
     from repro.optim.grad_compress import compressed_psum_mean
-    from repro.runtime.compat import shard_map
 
     p = 8
     r = np.random.default_rng(3)
@@ -332,10 +330,10 @@ def test_grad_compression_sim_matches_shard_map_bitwise():
         return o[None], ne[None]
 
     with mesh:
-        out_sm, err_sm = jax.jit(shard_map(
+        out_sm, err_sm = jax.jit(jax.shard_map(
             blk, mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=(P("data"), P("data"))))(jnp.asarray(data),
-                                               jnp.asarray(err0))
+            out_specs=(P("data"), P("data")),
+            check_vma=False))(jnp.asarray(data), jnp.asarray(err0))
     out_sim, err_sim = jax.jit(comm.sim_map(body, "data", p))(
         jnp.asarray(data), jnp.asarray(err0))
     np.testing.assert_array_equal(np.asarray(out_sm), np.asarray(out_sim))
