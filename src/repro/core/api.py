@@ -36,7 +36,7 @@ import dataclasses
 import os
 import warnings
 from functools import partial
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -465,8 +465,50 @@ def psort(keys, config=None, *, return_info: bool = False, **legacy):
     ...     p=4, backend="sim", external=ExternalPolicy(budget=4)))
     >>> np.array_equal(np.asarray(out), np.sort(big))
     True
+
+    **Profiler spans** — each call writes ``jax.profiler.TraceAnnotation``
+    spans on the calling thread, on the profiler's clock: ``psort`` around
+    the whole call and, on the in-core paths, its children in this order:
+    ``psort.prepare`` (config, upload, ``key_to_uint``, padding, algorithm
+    selection and the dispatch of the device program), ``psort.wait``
+    (``block_until_ready`` on its outputs), ``psort.pull`` (device → host;
+    ``bytes=`` the bytes pulled) and ``psort.assemble`` (the per-PE joins,
+    the answer's upload and ``uint_to_key``; ``bytes=`` the answer's
+    bytes).  The fault-policy and external paths run after
+    ``psort.prepare`` under ``psort`` alone.  The device program carries
+    ``jax.named_scope`` phase names (each ``comm.tagged`` tag,
+    ``local_sort``, ``merge_shards``, ``partition_buckets``,
+    ``alltoall_route``) in its ops' ``op_name`` metadata.
     """
-    cfg = _coerce_config(config, legacy, caller="psort")
+    with jax.profiler.TraceAnnotation("psort"):
+        with jax.profiler.TraceAnnotation("psort.prepare"):
+            cfg = _coerce_config(config, legacy, caller="psort")
+            launched = _launch(keys, cfg, return_info)
+        if not isinstance(launched, _Launched):
+            return launched()              # the fault-policy or external path
+        return _collect(launched, return_info)
+
+
+class _Launched(NamedTuple):
+    """An in-core sort whose device program is dispatched: its padded
+    per-PE outputs, shaped ``(d, p, ...)``, and what assembling them needs."""
+    keys: jax.Array
+    idx: jax.Array
+    counts: jax.Array
+    overflow: jax.Array
+    n: int
+    batched: bool
+    algorithm: str
+    orig_dtype: np.dtype
+    cfg: SortConfig
+
+
+def _launch(keys, cfg: SortConfig, return_info: bool):
+    """``psort`` up to the return of the jitted device program.
+
+    Returns a :class:`_Launched`, or, for the fault-policy and external
+    paths, a callable that runs the rest of the sort and returns ``psort``'s
+    result."""
     p, algorithm, mesh = cfg.p, cfg.algorithm, cfg.mesh
     axis, data_axis = cfg.axis, cfg.data_axis
     mesh_shape, mesh_axes, levels = cfg.mesh_shape, cfg.mesh_axes, cfg.levels
@@ -552,7 +594,8 @@ def psort(keys, config=None, *, return_info: bool = False, **legacy):
         if backend != "sim":
             raise ValueError("fault_policy= requires backend='sim' (the "
                              "fault-injection lane runs on emulated PEs)")
-        return _psort_faulty(
+        return partial(
+            _psort_faulty,
             u, n, d, batched, orig_dtype, p=p, algorithm=algorithm,
             policy=fault_policy, axis=axis, data_axis=data_axis,
             mesh_shape=(p_o, p_i) if mesh_shape is not None else None,
@@ -569,9 +612,9 @@ def psort(keys, config=None, *, return_info: bool = False, **legacy):
             budget=external.budget if external is not None else None)
     if external is not None and (algorithm == "external"
                                  or per > external.budget):
-        return _psort_external(u, n, orig_dtype, p=p, axis=axis,
-                               policy=external, return_info=return_info,
-                               overlap=cfg.overlap)
+        return partial(_psort_external, u, n, orig_dtype, p=p, axis=axis,
+                       policy=external, return_info=return_info,
+                       overlap=cfg.overlap)
     if cfg.overlap and algorithm in _OVERLAP_ALGOS:
         algo_kw.setdefault("overlap", True)
     if algorithm in ("rams", "ntb-ams"):
@@ -638,32 +681,49 @@ def psort(keys, config=None, *, return_info: bool = False, **legacy):
         keys_out, idx_out = keys_out[None], idx_out[None]
         counts_out, overflow = counts_out[None], overflow[None]
 
-    keys_out = np.asarray(keys_out)                # (d, p, out_capacity)
-    counts_out = np.asarray(counts_out)            # (d, p)
-    pe_range = range(1) if algorithm == "allgatherm" else range(p)
-    rows = [np.concatenate([keys_out[r, i, :counts_out[r, i]]
-                            for i in pe_range]) for r in range(d)]
-    result = uint_to_key(jnp.asarray(np.stack(rows) if batched else rows[0]),
-                         orig_dtype)
-    if return_info:
-        idx_out = np.asarray(idx_out)
+    return _Launched(keys_out, idx_out, counts_out, overflow, n, batched,
+                     algorithm, orig_dtype, cfg)
+
+
+def _collect(job: _Launched, return_info: bool):
+    """The in-core layouts' shared tail: wait for the device program, pull
+    its padded per-PE outputs and assemble the answer from them."""
+    outs = (job.keys, job.counts) + ((job.idx, job.overflow)
+                                     if return_info else ())
+    with jax.profiler.TraceAnnotation("psort.wait"):
+        jax.block_until_ready(outs)
+    with jax.profiler.TraceAnnotation("psort.pull",
+                                      bytes=sum(o.nbytes for o in outs)):
+        outs = [np.asarray(o) for o in outs]
+    keys_out, counts_out = outs[:2]                # (d, p, out_capacity), (d, p)
+    n, (d, p) = job.n, counts_out.shape
+    pe_range = range(1) if job.algorithm == "allgatherm" else range(p)
+    answer_bytes = int(counts_out[:, pe_range].sum()) \
+        * np.dtype(job.orig_dtype).itemsize
+    with jax.profiler.TraceAnnotation("psort.assemble", bytes=answer_bytes):
+        rows = [np.concatenate([keys_out[r, i, :counts_out[r, i]]
+                                for i in pe_range]) for r in range(d)]
+        result = uint_to_key(
+            jnp.asarray(np.stack(rows) if job.batched else rows[0]),
+            job.orig_dtype)
+        if not return_info:
+            return result
+        idx_out, overflow = outs[2:]
         perms = [np.concatenate([idx_out[r, i, :counts_out[r, i]]
                                  for i in range(p)]) if n
                  else np.zeros((0,), np.uint32) for r in range(d)]
         info = {
-            "algorithm": algorithm,
-            "backend": backend,
-            "mesh_shape": tuple(mesh_shape) if mesh_shape is not None
-            else None,
-            "counts": counts_out if batched else counts_out[0],
-            "overflow": int(np.asarray(overflow).sum()),
+            "algorithm": job.algorithm,
+            "backend": job.cfg.backend,
+            "mesh_shape": job.cfg.mesh_shape,
+            "counts": counts_out if job.batched else counts_out[0],
+            "overflow": int(overflow.sum()),
             "balance": counts_out.max() / max(1.0, n / p),
-            "perm": np.stack(perms) if batched else perms[0],
+            "perm": np.stack(perms) if job.batched else perms[0],
             "n": n,
             "d": d,
         }
         return result, info
-    return result
 
 
 def _out_capacity(algorithm: str, n: int, p: int, per: int, capacity: int) -> int:

@@ -406,12 +406,15 @@ _TAG: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
 @contextlib.contextmanager
 def tagged(tag: Optional[str]):
     """Label every collective traced in this scope with an algorithm-phase
-    tag (recorded by :class:`CountingCollectives`; a no-op otherwise).
+    tag (recorded by :class:`CountingCollectives`), and every op with it as
+    a ``jax.named_scope`` (the ``op_name`` a profile shows).
     RAMS tags its initial shuffle and each level, which is what lets a
     counted trace attribute launches/bytes per level."""
     token = _TAG.set(tag)
     try:
-        yield
+        with (contextlib.nullcontext() if tag is None
+              else jax.named_scope(tag)):
+            yield
     finally:
         _TAG.reset(token)
 

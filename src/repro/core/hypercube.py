@@ -181,6 +181,7 @@ def alltoall_shuffle(shard: SortShard, axis_name: str, p: int, seed,
                            stream=stream)
 
 
+@jax.named_scope("alltoall_route")
 def _alltoall_route(shard: SortShard, dest: jax.Array, axis_name: str, p: int,
                     slot_cap: int, groups=None,
                     stream: bool = False) -> Tuple[SortShard, jax.Array]:

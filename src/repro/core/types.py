@@ -239,6 +239,7 @@ def set_pallas_local_sort(enabled: Optional[bool]) -> Optional[bool]:
     return prev
 
 
+@jax.named_scope("local_sort")
 def local_sort(shard: SortShard) -> SortShard:
     """Sort a shard's valid elements ascending (stable w.r.t. input order)."""
     pad = shard.pad
@@ -276,6 +277,7 @@ def _take(shard_keys, vals, order):
     return shard_keys[order], {k: v[order] for k, v in vals.items()}
 
 
+@jax.named_scope("merge_shards")
 def merge_shards(a: SortShard, b: SortShard, capacity: Optional[int] = None,
                  tie_a_first: bool = True):
     """Merge two sorted padded shards into one of size ``capacity``.

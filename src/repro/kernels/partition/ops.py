@@ -38,6 +38,7 @@ def _tile_rows(n_buckets: int) -> int:
     return max(8, min(64, (rows // 8) * 8))
 
 
+@jax.named_scope("partition_buckets")
 def partition_buckets(keys, ties, s_keys, s_ties, *, n_buckets: int,
                       count=None, inclusive: bool = True,
                       want_pos: bool = True, interpret=None,
