@@ -206,15 +206,17 @@ def _alltoall_route(shard: SortShard, dest: jax.Array, axis_name: str, p: int,
     # one-hot cumsum, whose p² blow-up (C itself is Θ(p·slot_cap) after a
     # shuffle) was the memory wall at p = 1024 on the sim backend.  The
     # assignment is identical: stable order ⇒ elements keep their original
-    # relative order within a destination bucket.
+    # relative order within a destination bucket.  Each element's bucket
+    # start comes from the p + 1 bucket bounds (dest lies in [0, p]): one
+    # gather from a (p + 1)-word table.
     cap_in = dest.shape[0]
     order = jnp.argsort(dest, stable=True)
     sorted_dest = dest[order]
-    first = jnp.searchsorted(sorted_dest, sorted_dest, side="left")
-    rank_in_bucket = jnp.arange(cap_in, dtype=jnp.int32) - first.astype(jnp.int32)
-    slot = jnp.zeros((cap_in,), jnp.int32).at[order].set(rank_in_bucket)
     bounds = jnp.searchsorted(sorted_dest, jnp.arange(p + 1, dtype=jnp.int32),
                               side="left")
+    first = bounds[sorted_dest]
+    rank_in_bucket = jnp.arange(cap_in, dtype=jnp.int32) - first.astype(jnp.int32)
+    slot = jnp.zeros((cap_in,), jnp.int32).at[order].set(rank_in_bucket)
     sent_counts = (bounds[1:] - bounds[:-1]).astype(jnp.int32)    # (p,)
     overflow = jnp.sum(jnp.maximum(sent_counts - slot_cap, 0))
     ok = (dest < p) & (slot < slot_cap)

@@ -101,6 +101,110 @@ def test_alltoall_shuffle_preserves_multiset():
     assert (got == np.sort(keys.ravel())).all()
 
 
+def _route_dests(case, p, rng):
+    """(p, C) per-PE destinations in [0, p]; p marks an invalid element."""
+    if case == "ragged":                       # C not a multiple of p
+        return rng.integers(0, p + 1, size=(p, 8 * p + 3))
+    C = 8 * p
+    if case == "random":
+        return rng.integers(0, p + 1, size=(p, C))
+    if case == "one_bucket":                   # every element to one PE
+        return np.broadcast_to((np.arange(p) * 3 % p)[:, None], (p, C)).copy()
+    if case == "all_invalid":
+        return np.full((p, C), p)
+    if case == "empty_buckets":                # odd PEs receive nothing
+        return rng.choice(np.append(np.arange(0, p, 2), p), size=(p, C))
+    raise ValueError(case)
+
+
+def _route_reference(keys, idx, dest, p, slot_cap, pad):
+    """The route's exact output layout, from NumPy's per-element ranks."""
+    C = dest.shape[1]
+    bufk = np.full((p, p, slot_cap), pad, keys.dtype)     # [receiver, source]
+    bufi = np.zeros((p, p, slot_cap), idx.dtype)
+    counts = np.zeros((p, p), np.int64)
+    overflow = np.zeros(p, np.int64)
+    for s in range(p):
+        order = np.argsort(dest[s], kind="stable")
+        sd = dest[s][order]
+        rank = np.arange(C) - np.searchsorted(sd, sd, "left")
+        slot = np.empty(C, np.int64)
+        slot[order] = rank
+        sent = np.bincount(dest[s], minlength=p + 1)[:p]
+        overflow[s] = np.maximum(sent - slot_cap, 0).sum()
+        counts[:, s] = np.minimum(sent, slot_cap)
+        ok = (dest[s] < p) & (slot < slot_cap)
+        bufk[dest[s][ok], s, slot[ok]] = keys[s][ok]
+        bufi[dest[s][ok], s, slot[ok]] = idx[s][ok]
+    out_k = np.full((p, p * slot_cap), pad, keys.dtype)
+    out_i = np.zeros((p, p * slot_cap), idx.dtype)
+    for r in range(p):
+        ks = np.concatenate([bufk[r, s, :counts[r, s]] for s in range(p)])
+        vs = np.concatenate([bufi[r, s, :counts[r, s]] for s in range(p)])
+        out_k[r, :ks.size], out_i[r, :vs.size] = ks, vs
+    return out_k, out_i, counts.sum(1), overflow
+
+
+@pytest.mark.parametrize("stream", [False, True], ids=["barrier", "stream"])
+@pytest.mark.parametrize("case", ["random", "one_bucket", "all_invalid",
+                                  "empty_buckets", "ragged"])
+@pytest.mark.parametrize("p", [1, 2, 4, 8])
+def test_alltoall_route_slots_match_reference(p, case, stream):
+    """Slot assignment, drops and overflow equal a per-element search."""
+    rng = np.random.default_rng(100 * p + len(case))
+    dest = _route_dests(case, p, rng).astype(np.int32)
+    C = dest.shape[1]
+    keys = rng.permutation(p * C).astype(np.uint32).reshape(p, C)
+    idx = np.arange(p * C, dtype=np.int32).reshape(p, C)
+    slot_cap = C // p + 2            # small enough for one bucket to overflow
+
+    def body(k, i, d):
+        sh = ct.make_shard(k, capacity=C, vals={"i": i}, sort_local=False)
+        out, ovf = hc._alltoall_route(sh, d, "sort", p, slot_cap,
+                                      stream=stream)
+        return out.keys, out.vals["i"], out.count[None], ovf[None]
+
+    ks, vs, cnt, ovf = (np.asarray(a) for a in _run(
+        body, keys.ravel(), idx.ravel(), dest.ravel(), p=p,
+        out_specs=(P("sort"),) * 4))
+    ref_k, ref_i, ref_cnt, ref_ovf = _route_reference(
+        keys, idx, dest, p, slot_cap, np.iinfo(np.uint32).max)
+    if stream:                       # the streamed route arrives sorted
+        for r in range(p):
+            o = np.argsort(ref_k[r], kind="stable")
+            ref_k[r], ref_i[r] = ref_k[r][o], ref_i[r][o]
+    np.testing.assert_array_equal(cnt, ref_cnt)
+    np.testing.assert_array_equal(ovf, ref_ovf)
+    np.testing.assert_array_equal(ks.reshape(p, -1), ref_k)
+    for r in range(p):               # vals beyond the count are unspecified
+        np.testing.assert_array_equal(vs.reshape(p, -1)[r, :ref_cnt[r]],
+                                      ref_i[r, :ref_cnt[r]])
+    if case == "one_bucket":         # the reference drops the excess
+        assert (ref_ovf == max(C - slot_cap, 0)).all()
+
+
+def test_alltoall_route_has_no_per_element_search():
+    """No while loop of the route carries a C-wide query: at most the
+    searched table (the bucket bounds take p + 1 queries)."""
+    C, p = 2 ** 12, 4
+
+    def body(k, d):
+        sh = ct.make_shard(k, capacity=C, sort_local=False)
+        out, ovf = hc._alltoall_route(sh, d, "sort", p, C // p + 64)
+        return out.keys, ovf[None]
+
+    mesh = _mesh(p)
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("sort"),) * 2,
+                              out_specs=(P("sort"),) * 2, check_vma=False))
+    txt = f.lower(jax.ShapeDtypeStruct((p * C,), jnp.uint32),
+                  jax.ShapeDtypeStruct((p * C,), jnp.int32)).as_text()
+    loops = [ln.split(") : ", 1)[1] for ln in txt.splitlines()
+             if "stablehlo.while(" in ln]
+    assert loops, "the bounds search no longer lowers to a while loop"
+    for carry in loops:
+        assert carry.count(f"tensor<{C}xi32>") <= 1, carry
+
+
 def test_distributions_shapes_and_ranges():
     from repro.data.distributions import INSTANCES, generate_instance
     for name in INSTANCES:
