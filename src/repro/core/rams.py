@@ -2,16 +2,18 @@
 
 Per level, within the current subcube of size p_sub (split into k = 2^b
 groups):
-  1. sample locally *with tie-breakers*: sample composite = (key, pe, pos)
-     packed in one u64 — tie-break info is attached to the O(k log k)
-     samples only, never to the data elements (the paper's low-overhead
-     scheme);
+  1. sample locally *with tie-breakers*: a sample is its key and a 32-bit
+     tag mixed from (pe, pos) — one u64 composite (key << 32 | tag) for
+     u32 keys, three u32 planes (hi, lo, tag) for u64 keys.  Tie-break
+     info is attached to the O(k log k) samples only, never to the data
+     elements (the paper's low-overhead scheme);
   2. all-gather the samples inside the subcube (grouped collective — the
      TPU analogue of ranking samples with FIS: one fused all-gather beats
      emulating the 2-D grid for tiny arrays, cf. DESIGN.md §2);
   3. select n_b = b·k splitters, classify local elements into n_b buckets
      (Super Scalar Sample Sort classifier with implicit tie-breaking:
-     an element's composite is formed *locally* from (key, own_pe, own_pos));
+     an element's tag is formed *locally* from (own_pe, own_pos), and its
+     u32 planes compare lexicographically against the splitters');
   4. psum the bucket histogram, greedily assign contiguous bucket ranges to
      the k groups (ε-balance: imbalance ≤ max bucket ≈ total/(b·k));
   5. compute each element's target PE inside its group from its *global*
@@ -28,6 +30,7 @@ random sample of its subcube's data at every level).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional, Sequence
 
@@ -44,6 +47,7 @@ from repro.kernels.partition import partition_buckets
 _PE_BITS = 12
 _POS_BITS = 20
 _HI64 = np.uint64(0xFFFFFFFFFFFFFFFF)
+_ONES32 = np.uint32(0xFFFFFFFF)
 
 
 class RAMSResult(NamedTuple):
@@ -114,12 +118,31 @@ def _mix32(x):
     return x
 
 
+def _tag(pe, pos):
+    """The 32-bit tie-break tag of the element at ``pos`` on PE ``pe``."""
+    return _mix32((pe.astype(jnp.uint32) << np.uint32(_POS_BITS))
+                  | pos.astype(jnp.uint32))
+
+
 def _composite(keys_u32, pe, pos, valid):
-    tag = _mix32((pe.astype(jnp.uint32) << np.uint32(_POS_BITS))
-                 | pos.astype(jnp.uint32))
+    tag = _tag(pe, pos)
     c = (keys_u32.astype(jnp.uint64) << np.uint64(_PE_BITS + _POS_BITS)) \
         | tag.astype(jnp.uint64)
     return jnp.where(valid, c, _HI64)
+
+
+def _split64(keys_u64):
+    """(hi, lo) u32 planes of u64 keys."""
+    return ((keys_u64 >> np.uint64(32)).astype(jnp.uint32),
+            keys_u64.astype(jnp.uint32))
+
+
+def _sample_planes(keys_u64, pe, pos, valid, tie_break: bool):
+    """(s, 3) u32 samples (hi, lo, tag) of u64 keys; all-ones when
+    invalid, a zero tag without tie-breaking."""
+    tag = _tag(pe, pos) if tie_break else jnp.zeros(pos.shape, jnp.uint32)
+    planes = jnp.stack(_split64(keys_u64) + (tag,), axis=1)
+    return jnp.where(valid[:, None], planes, _ONES32)
 
 
 def quantile_splitters(sorted_samples, nb: int, invalid=_HI64):
@@ -127,13 +150,23 @@ def quantile_splitters(sorted_samples, nb: int, invalid=_HI64):
 
     The shared splitter pick of RAMS, samplesort, and the external lane:
     ``sorted_samples`` is an ascending u64 composite array whose invalid
-    entries equal ``invalid`` (and therefore sort to the tail); the i-th
-    splitter is the element at rank ``i * n_valid // nb``.  Extracted so
-    the three callers stay bitwise-identical.
+    entries equal ``invalid`` (and therefore sort to the tail), or a tuple
+    of u32 planes in lexicographic order (RAMS on 64-bit keys) whose
+    invalid entries are all-ones in every plane; the i-th splitter is the
+    entry at rank ``i * n_valid // nb``, as a tuple of planes for a tuple.
+    Extracted so the callers stay bitwise-identical.
     """
-    n_valid = jnp.sum(sorted_samples != invalid)
-    q = (jnp.arange(1, nb, dtype=jnp.int64) * n_valid) // nb
-    return sorted_samples[jnp.clip(q, 0, sorted_samples.shape[0] - 1)]
+    def ranks(n_valid, size):
+        q = (jnp.arange(1, nb, dtype=jnp.int64) * n_valid) // nb
+        return jnp.clip(q, 0, size - 1)
+
+    if isinstance(sorted_samples, tuple):
+        valid = functools.reduce(jnp.logical_or,
+                                 [s != _ONES32 for s in sorted_samples])
+        at = ranks(jnp.sum(valid), sorted_samples[0].shape[0])
+        return tuple(s[at] for s in sorted_samples)
+    at = ranks(jnp.sum(sorted_samples != invalid), sorted_samples.shape[0])
+    return sorted_samples[at]
 
 
 def rams(shard: SortShard, axis_name: str, p: int, *,
@@ -142,8 +175,9 @@ def rams(shard: SortShard, axis_name: str, p: int, *,
          oversample: int = 4, tie_break: bool = True,
          shuffle: bool = True, slot_factor: float = 2.0,
          overlap: bool = False) -> RAMSResult:
-    """Sort over the whole axis.  Requires uint32 keys (u64 keys would need
-    a 128-bit sample composite; psort's key transform covers f32/i32/u32).
+    """Sort over the whole axis: uint32 keys, or uint64 keys (psort's
+    transform of f64 / i64 / u64), which classify as (hi, lo, tie) u32
+    planes.
 
     ``level_bits`` overrides the level schedule with an explicit per-level
     bit split (summing to log2 p, high bits first) — on a hierarchical
@@ -162,8 +196,6 @@ def rams(shard: SortShard, axis_name: str, p: int, *,
     blocks into a running merge instead of gathering-then-sorting —
     bitwise-identical output, see ``hypercube._stream_route_merge``.
     """
-    if shard.keys.dtype != jnp.uint32:
-        raise ValueError("rams requires uint32 keys (use psort's transform)")
     d = p.bit_length() - 1
     assert p.bit_count() == 1 and shard.capacity < (1 << _POS_BITS)
     if level_bits is not None:
@@ -233,45 +265,63 @@ def _rams_level(shard: SortShard, axis_name: str, p: int, h: int, b: int,
     groups = subcube_groups(p, h)
     sub_dims = list(range(h))
 
-    # --- 1. local samples with tie-break composites ------------------------
+    # --- 1. local samples with tie-break tags -----------------------------
     # sample count scales with the *bucket* count nb (not just k): splitter
     # quantiles must resolve bucket-width mass, else the last level
     # (p_g = 1, where group total == PE load) inherits the full sampling
     # error and breaks the 2× capacity bound (observed at p = 64).
+    # A u32 key and its tag pack into one u64 composite; a u64 key and its
+    # tag are three u32 planes (hi, lo, tag), one (s_per, 3) row each.
+    wide = shard.keys.dtype == jnp.uint64
     s_per = max(1, -(-(2 * nb * max(2, int(math.log2(p_sub + 1)))) // p_sub))
     key = jax.random.fold_in(jax.random.fold_in(jax.random.PRNGKey(seed), me), 1)
     pos = jax.random.randint(key, (s_per,), 0, jnp.maximum(shard.count, 1))
     sample_keys = shard.keys[pos]
     valid = (shard.count > 0)
-    samp = _composite(sample_keys, jnp.broadcast_to(sub_rel, (s_per,)),
-                      pos, valid & (pos < shard.count))
-    if not tie_break:
-        samp = jnp.where(samp == _HI64, samp,
-                         samp & ~np.uint64((1 << (_PE_BITS + _POS_BITS)) - 1))
+    sample_pe = jnp.broadcast_to(sub_rel, (s_per,))
+    if wide:
+        samp = _sample_planes(sample_keys, sample_pe, pos,
+                              valid & (pos < shard.count), tie_break)
+    else:
+        samp = _composite(sample_keys, sample_pe, pos,
+                          valid & (pos < shard.count))
+        if not tie_break:
+            samp = jnp.where(
+                samp == _HI64, samp,
+                samp & ~np.uint64((1 << (_PE_BITS + _POS_BITS)) - 1))
 
     # --- 2. gather + sort samples within subcube ---------------------------
     all_samp = comm.all_gather(samp, axis_name, axis_index_groups=groups,
                                tiled=True)
-    all_samp = jnp.sort(all_samp)
+    with jax.named_scope("splitters"):
+        if wide:
+            all_samp = tuple(jax.lax.sort(tuple(all_samp.T), num_keys=3))
+        else:
+            all_samp = jnp.sort(all_samp)
 
-    # --- 3. select splitters, classify -------------------------------------
-    splitters = quantile_splitters(all_samp, nb)                  # (nb-1,)
+        # --- 3. select splitters, classify ---------------------------------
+        splitters = quantile_splitters(all_samp, nb)              # (nb-1,)
     # fused SSSS classify + histogram + stable in-bucket rank.  Element
-    # composites never materialize as u64: the (key, tag) planes compare
-    # lexicographically, which equals the u64 compare since the tag is
-    # exactly 32 bits.  Invalid entries (flat index ≥ count — pads sit at
-    # the tail of a locally-sorted shard) go to the trash bucket nb.
+    # composites never materialize: the (key, tag) or (hi, lo, tag) planes
+    # compare lexicographically, which equals the composite compare since
+    # the tag is exactly 32 bits.  Invalid entries (flat index ≥ count —
+    # pads sit at the tail of a locally-sorted shard) go to the trash
+    # bucket nb.
     elem_pos = jnp.arange(cap, dtype=jnp.int32)
     if tie_break:
-        e_ties = _mix32((jnp.broadcast_to(sub_rel, (cap,)).astype(jnp.uint32)
-                         << np.uint32(_POS_BITS))
-                        | elem_pos.astype(jnp.uint32))
+        e_ties = _tag(jnp.broadcast_to(sub_rel, (cap,)), elem_pos)
     else:
         e_ties = jnp.zeros((cap,), jnp.uint32)
-    s_keys = (splitters >> np.uint64(_PE_BITS + _POS_BITS)).astype(jnp.uint32)
-    s_ties = splitters.astype(jnp.uint32)            # low 32 bits
+    if wide:
+        e_keys, s_keys, s_ties = _split64(shard.keys), splitters[:2], \
+            splitters[2]
+    else:
+        e_keys = shard.keys
+        s_keys = (splitters >> np.uint64(_PE_BITS + _POS_BITS)
+                  ).astype(jnp.uint32)
+        s_ties = splitters.astype(jnp.uint32)        # low 32 bits
     bucket, q_in_bucket, hist = partition_buckets(
-        shard.keys, e_ties, s_keys, s_ties, n_buckets=nb, count=shard.count)
+        e_keys, e_ties, s_keys, s_ties, n_buckets=nb, count=shard.count)
 
     # --- 4. histogram psum, greedy contiguous group assignment -------------
     hist = hist.astype(jnp.int64)                                   # (nb,)

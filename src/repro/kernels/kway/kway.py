@@ -38,7 +38,7 @@ _ZERO = np.int32(0)               # int32 index: a Python 0 is i64 under x64
 
 def _kway_kernel(keys_ref, ties_ref, sk_ref, st_ref, bucket_ref, hist_ref,
                  *, n_buckets: int):
-    bucket = classify(keys_ref[...], ties_ref[...], sk_ref, st_ref,
+    bucket = classify([keys_ref[...], ties_ref[...]], [sk_ref, st_ref],
                       sk_ref.shape[1])
     bucket_ref[...] = bucket
     lane = jax.lax.broadcasted_iota(jnp.int32, hist_ref.shape, 1)

@@ -14,15 +14,16 @@ identical by tests/test_partition.py — and hides the tiling:
 Kernel-vs-ref selection: an explicit ``use_kernel`` wins; ``None`` defers
 to :func:`repro.core.types.local_kernels` (the ``REPRO_LOCAL_KERNELS``
 policy — default on for TPU backends, off elsewhere).  The ref handles
-every case; the kernel additionally requires uint32 planes, 2 ≤ nb ≤
-``MAX_BUCKETS`` and at least one full lane row.
+every case; the kernel additionally requires two or three uint32 planes
+— (key, tie) or (hi, lo, tie) — 2 ≤ nb ≤ ``MAX_BUCKETS`` and at least one
+full lane row.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from .partition import LANES, partition_tile
+from .partition import LANES, key_planes, partition_tile
 from .ref import partition_ref
 
 # Tile sizes are those of the (R, 128, nb+1) one-hot formulation the kernel
@@ -46,15 +47,19 @@ def partition_buckets(keys, ties, s_keys, s_ties, *, n_buckets: int,
     """Fused classify + rank + histogram over a locally-sorted shard.
 
     Same contract as :func:`repro.kernels.partition.ref.partition_ref`
-    (see there for argument semantics); ``use_kernel`` selects the Pallas
-    path (None → the ``local_kernels()`` policy)."""
+    (see there for argument semantics: ``keys`` / ``s_keys`` are one u32
+    plane or a tuple of planes); ``use_kernel`` selects the Pallas path
+    (None → the ``local_kernels()`` policy)."""
     if use_kernel is None:
         from repro.core.types import local_kernels
         use_kernel = local_kernels().partition
-    C = keys.shape[0]
+    planes = key_planes(keys) + (ties,)
+    C = planes[0].shape[0]
     eligible = (use_kernel and C >= LANES and 2 <= n_buckets <= MAX_BUCKETS
-                and keys.dtype == jnp.uint32 and ties.dtype == jnp.uint32
-                and s_keys.dtype == jnp.uint32 and s_ties.dtype == jnp.uint32)
+                and len(planes) <= 3
+                and all(x.dtype == jnp.uint32 for x in planes)
+                and all(x.dtype == jnp.uint32
+                        for x in key_planes(s_keys) + (s_ties,)))
     if not eligible:
         return partition_ref(keys, ties, s_keys, s_ties, n_buckets=n_buckets,
                              count=count, inclusive=inclusive,
@@ -70,8 +75,7 @@ def partition_buckets(keys, ties, s_keys, s_ties, *, n_buckets: int,
     pad = n_tiles * tile - C
     if pad:                     # pads classify as trash (flat ≥ nvalid)
         fill = jnp.full((pad,), 0xFFFFFFFF, jnp.uint32)
-        keys = jnp.concatenate([keys, fill])
-        ties = jnp.concatenate([ties, fill])
+        planes = tuple(jnp.concatenate([x, fill]) for x in planes)
 
     def step(hist, xs):         # one launch per tile, histogram threaded
         k, t, off = xs
@@ -81,10 +85,11 @@ def partition_buckets(keys, ties, s_keys, s_ties, *, n_buckets: int,
                                     interpret=interpret)
         return hist, (b, q)
 
+    hist0 = jnp.zeros((1, n_buckets + 1), jnp.int32)
+    tiles = tuple(x.reshape(n_tiles, R, LANES) for x in planes)
     hist, (bucket, pos) = jax.lax.scan(
-        step, jnp.zeros((1, n_buckets + 1), jnp.int32),
-        (keys.reshape(n_tiles, R, LANES), ties.reshape(n_tiles, R, LANES),
-         jnp.arange(n_tiles, dtype=jnp.int32) * tile))
+        step, hist0,
+        (tiles[:-1], tiles[-1], jnp.arange(n_tiles, dtype=jnp.int32) * tile))
     bucket = bucket.reshape(-1)[:C]
     pos = pos.reshape(-1)[:C] if want_pos else None
     return bucket, pos, hist[0, :n_buckets]

@@ -5,9 +5,10 @@ routing needs (the IPS⁴o block-partition shape, arXiv 2009.13569, mapped
 onto the VPU):
 
   * classify: branchless SSSS ``#splitters ≤ elem`` as a lexicographic
-    (key, tie) compare of the tile against each of the S = nb-1 splitters,
-    read as scalars from SMEM — no u64 composites materialize, the two u32
-    planes compare directly;
+    compare over u32 planes — (key, tie) for 32-bit keys, (hi, lo, tie)
+    for 64-bit ones — of the tile against each of the S = nb-1 splitters,
+    read as scalars from SMEM, one SMEM row per plane: no wide composite
+    materializes, the planes compare directly;
   * histogram + stable rank: for each bucket b, the 0/1 plane ``bucket ==
     b`` gets an inclusive prefix sum along lanes and an exclusive one over
     the row totals along sublanes, both as log-step roll-and-add scans.
@@ -29,8 +30,9 @@ threading the running histogram through a ``lax.scan`` of launches;
 ``prev_hist[bucket] + rank_in_tile`` is then the global stable send
 position.
 
-On a TPU the kernel compiles through Mosaic; elsewhere it runs in the
-Pallas interpreter (:func:`repro.kernels.interpret_mode`).
+The ``pallas_call`` is named :data:`KERNEL_NAME`, which is what a chip
+trace calls its op.  On a TPU the kernel compiles through Mosaic; elsewhere
+it runs in the Pallas interpreter (:func:`repro.kernels.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -45,6 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import interpret_mode
 
 LANES = 128
+KERNEL_NAME = "partition_planes"   # the op's name in a chip trace
 _ZERO = np.int32(0)          # int32 block index: a Python 0 is i64 under x64
 
 
@@ -67,24 +70,27 @@ def loop(n: int, body, init):
     return jax.lax.scan(step, (np.int32(0), init), None, length=n)[0][1]
 
 
-def classify(k, t, sk_ref, st_ref, n_split: int, inclusive: bool = True):
-    """#splitters ≤ (k, t) (``inclusive``) or < (k, t), lexicographically,
-    for a tile of u32 planes against ``n_split`` splitters held as (1, S)
-    SMEM rows."""
+def classify(planes, s_refs, n_split: int, inclusive: bool = True):
+    """#splitters ≤ e (``inclusive``) or < e, lexicographically over the u32
+    ``planes`` of e (most significant first, the tie plane last), for a
+    tile of planes against ``n_split`` splitters held as (1, S) SMEM rows,
+    one row per plane."""
     def step(s, bucket):
-        sk, st = sk_ref[0, s], st_ref[0, s]
-        tie = (st <= t) if inclusive else (st < t)
-        return bucket + ((sk < k) | ((sk == k) & tie)).astype(jnp.int32)
-    return loop(n_split, step, jnp.zeros(k.shape, jnp.int32))
+        sv = [r[0, s] for r in s_refs]
+        le = (sv[-1] <= planes[-1]) if inclusive else (sv[-1] < planes[-1])
+        for sp, ep in zip(sv[-2::-1], planes[-2::-1]):
+            le = (sp < ep) | ((sp == ep) & le)
+        return bucket + le.astype(jnp.int32)
+    return loop(n_split, step, jnp.zeros(planes[0].shape, jnp.int32))
 
 
-def _partition_kernel(keys_ref, ties_ref, sk_ref, st_ref, ph_ref, nv_ref,
-                      bucket_ref, pos_ref, hist_ref, *,
-                      n_buckets: int, inclusive: bool):
-    R = keys_ref.shape[0]
+def _partition_kernel(*refs, n_planes: int, n_buckets: int, inclusive: bool):
+    planes, s_refs = refs[:n_planes], refs[n_planes:2 * n_planes]
+    ph_ref, nv_ref, bucket_ref, pos_ref, hist_ref = refs[2 * n_planes:]
+    R = planes[0].shape[0]
     shape = (R, LANES)
-    bucket = classify(keys_ref[...], ties_ref[...], sk_ref, st_ref,
-                      n_buckets - 1, inclusive)
+    bucket = classify([r[...] for r in planes], s_refs, n_buckets - 1,
+                      inclusive)
     r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     l = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     bucket = jnp.where(r * LANES + l < nv_ref[0, 0], bucket,
@@ -114,15 +120,25 @@ def _partition_kernel(keys_ref, ties_ref, sk_ref, st_ref, ph_ref, nv_ref,
     hist_ref[...] = hist
 
 
+def key_planes(keys) -> tuple:
+    """The key planes of an argument that is one u32 plane or a tuple."""
+    return tuple(keys) if isinstance(keys, (tuple, list)) else (keys,)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("n_buckets", "inclusive", "interpret"))
 def partition_tile(keys2, ties2, s_keys, s_ties, prev_hist, nvalid, *,
                    n_buckets: int, inclusive: bool = True, interpret=None):
-    """Partition one (R, 128) tile.  ``prev_hist`` is the (1, nb+1) running
-    histogram of earlier tiles (trash bucket included); ``nvalid`` is a
-    (1, 1) int32 count of valid elements in this tile (flat order).
+    """Partition one (R, 128) tile.  ``keys2`` is one (R, 128) u32 key
+    plane or a tuple of them (most significant first), ``s_keys`` the
+    splitters' matching (S,) planes; elements compare lexicographically on
+    (key planes…, tie).  ``prev_hist`` is the (1, nb+1) running histogram
+    of earlier tiles (trash bucket included); ``nvalid`` is a (1, 1) int32
+    count of valid elements in this tile (flat order).
     Returns (bucket (R,128), pos (R,128), new_hist (1, nb+1))."""
-    R = keys2.shape[0]
+    planes = key_planes(keys2) + (ties2,)
+    s_planes = key_planes(s_keys) + (s_ties,)
+    R = planes[0].shape[0]
     nbt = n_buckets + 1
     width = -(-nbt // LANES) * LANES                 # lane-aligned histogram
     hist = jnp.pad(prev_hist, ((0, 0), (0, width - nbt)))
@@ -131,16 +147,16 @@ def partition_tile(keys2, ties2, s_keys, s_ties, prev_hist, nvalid, *,
     hblk = pl.BlockSpec((1, width), whole)
     sblk = pl.BlockSpec((1, n_buckets - 1), whole, memory_space=pltpu.SMEM)
     one = pl.BlockSpec((1, 1), whole, memory_space=pltpu.SMEM)
-    kern = functools.partial(_partition_kernel, n_buckets=n_buckets,
-                             inclusive=inclusive)
+    kern = functools.partial(_partition_kernel, n_planes=len(planes),
+                             n_buckets=n_buckets, inclusive=inclusive)
     bucket, pos, hist = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((R, LANES), jnp.int32),
                    jax.ShapeDtypeStruct((R, LANES), jnp.int32),
                    jax.ShapeDtypeStruct((1, width), jnp.int32)),
-        in_specs=[blk, blk, sblk, sblk, hblk, one],
+        in_specs=[blk] * len(planes) + [sblk] * len(planes) + [hblk, one],
         out_specs=(blk, blk, hblk),
         grid=(1,), interpret=interpret_mode(interpret),
-    )(keys2, ties2, s_keys.reshape(1, -1), s_ties.reshape(1, -1), hist,
-      nvalid)
+        name=KERNEL_NAME,
+    )(*planes, *(s.reshape(1, -1) for s in s_planes), hist, nvalid)
     return bucket, pos, hist[:, :nbt]

@@ -43,13 +43,20 @@ def clean_policy(monkeypatch):
 def onehot_oracle(keys, ties, s_keys, s_ties, *, n_buckets, count,
                   inclusive=True):
     """O(n·nb) one-hot classify/rank/histogram — the formulation the fused
-    primitive replaced (rams._rams_level pre-rewrite, kernels/kway ref)."""
-    elem = (keys.astype(np.uint64) << np.uint64(32)) | ties.astype(np.uint64)
-    spl = (s_keys.astype(np.uint64) << np.uint64(32)) | s_ties.astype(np.uint64)
-    cmp = spl[None, :] <= elem[:, None] if inclusive \
-        else spl[None, :] < elem[:, None]
+    primitive replaced (rams._rams_level pre-rewrite, kernels/kway ref).
+    ``keys`` / ``s_keys`` are one u32 plane or a tuple of planes (most
+    significant first); with the tie plane last they compare
+    lexicographically."""
+    def planes(k, t):
+        k = k if isinstance(k, tuple) else (k,)
+        return [x.astype(np.int64) for x in k + (t,)]
+    e = [x[:, None] for x in planes(keys, ties)]
+    s = [x[None, :] for x in planes(s_keys, s_ties)]
+    cmp = (s[-1] <= e[-1]) if inclusive else (s[-1] < e[-1])
+    for sp, ep in zip(s[-2::-1], e[-2::-1]):
+        cmp = (sp < ep) | ((sp == ep) & cmp)
     bucket = cmp.sum(axis=1).astype(np.int32)
-    C = keys.shape[0]
+    C = e[0].shape[0]
     bucket = np.where(np.arange(C) < count, bucket, np.int32(n_buckets))
     onehot = bucket[:, None] == np.arange(n_buckets + 1)[None, :]
     hist = onehot[:, :n_buckets].sum(axis=0).astype(np.int32)
@@ -206,6 +213,120 @@ def test_partition_kernel_falls_back_below_lane_width():
     np.testing.assert_array_equal(np.asarray(kb), np.asarray(rb))
     np.testing.assert_array_equal(np.asarray(kp), np.asarray(rp))
     np.testing.assert_array_equal(np.asarray(kh), np.asarray(rh))
+
+
+# ---------------------------------------------------------------------------
+# three planes: (hi, lo, tie) of 64-bit keys
+# ---------------------------------------------------------------------------
+
+def _case3(name, C, n_buckets, count, seed=0, hi_from=None):
+    """A locally-sorted shard of 64-bit keys as (hi, lo, tie) planes, and
+    splitters drawn from it.  ``hi_from`` names the instance of the high
+    word (default: the low word's own instance); duplicate instances make
+    keys equal in (hi, lo) so only the tie plane decides."""
+    gen = INSTANCES[name]
+    lo = gen(3, 8, count, seed=seed).astype(np.uint64)
+    hi = INSTANCES[hi_from or name](5, 8, count, seed=seed + 7).astype(
+        np.uint64)
+    wide = np.full(C, 2**64 - 1, np.uint64)
+    wide[:count] = np.sort((hi << np.uint64(32)) | lo)
+    e_hi = (wide >> np.uint64(32)).astype(np.uint32)
+    e_lo = wide.astype(np.uint32)
+    ties = _mix(np.arange(C, dtype=np.uint32))
+    ties[count:] = 0xFFFFFFFF
+    rng = np.random.default_rng(seed + 1)
+    pick = rng.integers(0, max(count, 1), size=n_buckets - 1)
+    s_wide = wide[pick] if count else np.zeros(n_buckets - 1, np.uint64)
+    s_tie = _mix(np.arange(n_buckets - 1, dtype=np.uint32) * np.uint32(7))
+    # nondecreasing under (hi, lo, tie); equal keys keep tie order
+    order = np.lexsort((s_tie, s_wide))
+    s_wide, s_tie = s_wide[order], s_tie[order]
+    return ((e_hi, e_lo), ties,
+            ((s_wide >> np.uint64(32)).astype(np.uint32),
+             s_wide.astype(np.uint32)), s_tie)
+
+
+THREE_PLANE_CASES = [
+    ("Uniform", 1024, 64, 1024, None), ("Uniform", 1000, 8, 777, None),
+    ("Zero", 1024, 64, 1024, None),          # one key: ties alone decide
+    ("DeterDupl", 512, 32, 512, None),       # three keys, ties decide
+    ("Uniform", 512, 16, 500, "Zero"),       # equal hi, lo decides
+    ("Zero", 384, 16, 300, "Uniform"),       # equal lo, hi decides
+    ("RandDupl", 257, 128, 200, "DeterDupl"), ("Staggered", 2048, 2, 2048,
+                                               None),
+    ("Uniform", 256, 16, 0, None),
+]
+
+
+@pytest.mark.parametrize("name,C,nb,count,hi_from", THREE_PLANE_CASES)
+@pytest.mark.parametrize("inclusive", [True, False])
+def test_partition_ref_three_planes_matches_onehot_oracle(
+        name, C, nb, count, hi_from, inclusive):
+    keys, ties, sk, st = _case3(name, C, nb, count, hi_from=hi_from)
+    ob, op, oh = onehot_oracle(keys, ties, sk, st, n_buckets=nb,
+                               count=count, inclusive=inclusive)
+    rb, rp, rh = jax.jit(
+        lambda *a: partition_ref(*a, n_buckets=nb, count=count,
+                                 inclusive=inclusive)
+    )(keys, ties, sk, st)
+    np.testing.assert_array_equal(np.asarray(rb), ob)
+    np.testing.assert_array_equal(np.asarray(rh), oh)
+    np.testing.assert_array_equal(np.asarray(rp)[:count], op[:count])
+
+
+@pytest.mark.parametrize("name,C,nb,count,hi_from", THREE_PLANE_CASES)
+@pytest.mark.parametrize("inclusive", [True, False])
+def test_partition_kernel_three_planes_matches_ref(name, C, nb, count,
+                                                   hi_from, inclusive):
+    keys, ties, sk, st = _case3(name, C, nb, count, hi_from=hi_from)
+    keys, sk = tuple(map(jnp.asarray, keys)), tuple(map(jnp.asarray, sk))
+    ties, st = jnp.asarray(ties), jnp.asarray(st)
+    kb, kp, kh = partition_buckets(keys, ties, sk, st, n_buckets=nb,
+                                   count=count, inclusive=inclusive,
+                                   use_kernel=True)
+    rb, rp, rh = partition_buckets(keys, ties, sk, st, n_buckets=nb,
+                                   count=count, inclusive=inclusive,
+                                   use_kernel=False)
+    np.testing.assert_array_equal(np.asarray(kb), np.asarray(rb))
+    np.testing.assert_array_equal(np.asarray(kp), np.asarray(rp))
+    np.testing.assert_array_equal(np.asarray(kh), np.asarray(rh))
+    assert int(np.asarray(kh).sum()) == count
+
+
+def test_three_planes_tie_plane_alone_decides():
+    """Every element and splitter share (hi, lo): the bucket is the count
+    of splitter ties ≤ the element's tie, on both paths."""
+    C, nb = 512, 16
+    hi = np.full(C, 0xDEADBEEF, np.uint32)
+    lo = np.full(C, 0x12345678, np.uint32)
+    ties = _mix(np.arange(C, dtype=np.uint32))
+    st = np.sort(_mix(np.arange(nb - 1, dtype=np.uint32) + np.uint32(C)))
+    sk = (hi[:nb - 1], lo[:nb - 1])
+    want = (st[None, :] <= ties[:, None]).sum(axis=1)
+    for use_kernel in (True, False):
+        b, _, h = partition_buckets((hi, lo), ties, sk, st, n_buckets=nb,
+                                    count=C, use_kernel=use_kernel)
+        np.testing.assert_array_equal(np.asarray(b), want)
+        assert int(np.asarray(h).sum()) == C
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+def test_two_planes_unchanged_by_a_constant_high_plane(use_kernel):
+    """A constant high plane in front of (key, tie) changes nothing, and a
+    one-plane tuple is the plain two-plane call."""
+    keys, ties, sk, st = _case("RandDupl", 1000, 64, 900)
+    two = partition_buckets(keys, ties, sk, st, n_buckets=64, count=900,
+                            use_kernel=use_kernel)
+    one = partition_buckets((keys,), ties, (sk,), st, n_buckets=64,
+                            count=900, use_kernel=use_kernel)
+    hi = np.full_like(keys, 3)
+    three = partition_buckets((hi, keys), ties, (hi[:63], sk), st,
+                              n_buckets=64, count=900, use_kernel=use_kernel)
+    ob, op, oh = onehot_oracle(keys, ties, sk, st, n_buckets=64, count=900)
+    for got in (two, one, three):
+        np.testing.assert_array_equal(np.asarray(got[0]), ob)
+        np.testing.assert_array_equal(np.asarray(got[1])[:900], op[:900])
+        np.testing.assert_array_equal(np.asarray(got[2]), oh)
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,7 @@ from repro.kernels.bitonic import bitonic
 from repro.kernels.bitonic.ops import local_sort_fast
 from repro.kernels.kway import kway
 from repro.kernels.partition import partition_tile
+from repro.kernels.partition.partition import KERNEL_NAME
 from repro.kernels.partition.ops import _tile_rows
 
 TILE = 1 << 14              # one bitonic VMEM tile (MAX_TILE)
@@ -81,6 +82,22 @@ def test_partition_tile_compiles(one_chip, nb):
         jax.ShapeDtypeStruct((1, nb + 1), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip))
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("nb", [16, 512])
+def test_partition_tile_three_planes_compiles(one_chip, nb):
+    # (hi, lo, tie) planes of 64-bit keys; the op carries the kernel's name
+    R = _tile_rows(nb)
+    tile, spl = _u32(one_chip, R, 128), _u32(one_chip, nb - 1)
+    txt = _compile_text(
+        lambda hi, lo, t, shi, slo, st, h, nv: partition_tile(
+            (hi, lo), t, (shi, slo), st, h, nv, n_buckets=nb,
+            interpret=False),
+        tile, tile, tile, spl, spl, spl,
+        jax.ShapeDtypeStruct((1, nb + 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip))
+    assert "tpu_custom_call" in txt
+    assert f"%{KERNEL_NAME}" in txt
 
 
 def test_local_sort_fast_compiles_at_rams_shard(one_chip):
