@@ -225,8 +225,10 @@ def _sort_body(axis_name, p, algorithm, capacity, out_capacity, algo_kw):
         # global index payload proves permutation-ness in tests
         base = comm.axis_index(axis_name).astype(jnp.uint32) * np.uint32(per)
         idx = base + jnp.arange(per, dtype=jnp.uint32)
+        # unsorted: every algorithm sorts its own input (after its shuffle,
+        # where it has one), so a sort here would be repeated work
         shard = make_shard(keys_pe, count=count_pe, capacity=capacity,
-                           vals={"idx": idx})
+                           vals={"idx": idx}, sort_local=False)
         fn = _algorithm_fn(algorithm)
         out, overflow = fn(shard, axis_name, p, **algo_kw)
         overflow = overflow + jnp.maximum(out.count - out_capacity, 0)

@@ -78,12 +78,18 @@ def rquick(shard: SortShard, axis_name: str, p: int, *,
 
     Must be called inside shard_map.  Output: ascending over PE order,
     each shard locally sorted; elements never cross the subcube boundary.
+    The input need not be sorted.
+
+    The working capacity (default) is twice the input's when there is a
+    dimension to exchange along, for the imbalance the splits leave; with
+    empty ``dims`` (p = 1, or a subcube of one PE) nothing is exchanged,
+    so the shard keeps its capacity and is sorted once at that size.
     """
     d_all = p.bit_length() - 1
     dims = list(dims) if dims is not None else list(range(d_all))
     shuffle = robust if shuffle is None else shuffle
     tie_break = robust if tie_break is None else tie_break
-    cap = capacity or 2 * shard.capacity
+    cap = capacity or (2 * shard.capacity if dims else shard.capacity)
     overflow = jnp.int32(0)
 
     shard, _ = resize(shard, cap)
