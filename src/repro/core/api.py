@@ -32,11 +32,12 @@ it is emulated via ``comm.sim_map(..., mesh=(d, p))``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import warnings
 from functools import partial
-from typing import Dict, NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,8 +45,8 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import comm, selection
-from .types import (SortShard, key_to_uint, local_kernels, make_shard,
-                    pad_value, uint_to_key)
+from .types import (key_to_uint, local_kernels, make_shard, pad_value,
+                    uint_to_key)
 
 BACKENDS = ("shard_map", "sim")
 
@@ -60,8 +61,8 @@ class SortConfig:
     """Everything that shapes one distributed sort, in one hashable object.
 
     ``psort(keys, config=SortConfig(...))`` is the primary call style; the
-    jit caches key on the whole config, so two calls with equal configs hit
-    the same executable.  Fields group into:
+    device program's jit cache keys on the plan settled from it, so two
+    calls with equal configs hit the same executable.  Fields group into:
 
     **Topology** — ``p`` (PE count; read off ``mesh``/``mesh_shape`` when
     omitted on shard_map), ``mesh`` (explicit device mesh, shard_map only;
@@ -240,105 +241,226 @@ def _sort_body(axis_name, p, algorithm, capacity, out_capacity, algo_kw):
     return body
 
 
-@partial(jax.jit, static_argnames=("cfg", "algorithm", "axis_name", "p",
-                                   "capacity", "out_capacity", "mesh",
-                                   "algo_kw", "pallas"))
-def _psort_jit(keys2d, counts, mesh, cfg, axis_name, p, algorithm, capacity,
-               out_capacity, algo_kw, pallas):
-    body = _sort_body(axis_name, p, algorithm, capacity, out_capacity, algo_kw)
+@dataclasses.dataclass(frozen=True)
+class _Plan:
+    """What one sort runs, settled once by :func:`_plan`.
 
-    def blk(keys_blk, count_blk):
-        k, i, c, o = body(keys_blk[0], count_blk[0])
-        return k[None], i[None], c[None], o[None]
-
-    out = jax.shard_map(blk, mesh=mesh,
-                        in_specs=(P(axis_name), P(axis_name)),
-                        out_specs=(P(axis_name),) * 4,
-                        check_vma=False)(keys2d, counts)
-    return out
-
-
-@partial(jax.jit, static_argnames=("cfg", "algorithm", "axis_name", "p",
-                                   "capacity", "out_capacity", "algo_kw",
-                                   "pallas"))
-def _psort_sim_jit(keys2d, counts, cfg, axis_name, p, algorithm, capacity,
-                   out_capacity, algo_kw, pallas):
-    body = _sort_body(axis_name, p, algorithm, capacity, out_capacity, algo_kw)
-    return comm.sim_map(body, axis_name, p)(keys2d, counts)
-
-
-@partial(jax.jit, static_argnames=("cfg", "algorithm", "axis_name",
-                                   "data_axis", "p", "capacity",
-                                   "out_capacity", "mesh", "algo_kw",
-                                   "pallas"))
-def _psort2_jit(keys3d, counts, mesh, cfg, axis_name, data_axis, p, algorithm,
-                capacity, out_capacity, algo_kw, pallas):
-    """Batched psort over the sort axis of a 2-D (data, sort) device mesh."""
-    body = _sort_body(axis_name, p, algorithm, capacity, out_capacity, algo_kw)
-
-    def blk(keys_blk, count_blk):          # (1, 1, per), (1, 1)
-        k, i, c, o = body(keys_blk[0, 0], count_blk[0, 0])
-        return (k[None, None], i[None, None], c[None, None], o[None, None])
-
-    out = jax.shard_map(blk, mesh=mesh,
-                        in_specs=(P(data_axis, axis_name),
-                                  P(data_axis, axis_name)),
-                        out_specs=(P(data_axis, axis_name),) * 4,
-                        check_vma=False)(keys3d, counts)
-    return out
-
-
-@partial(jax.jit, static_argnames=("cfg", "algorithm", "axis_name",
-                                   "data_axis", "d", "p", "capacity",
-                                   "out_capacity", "algo_kw", "pallas"))
-def _psort2_sim_jit(keys3d, counts, cfg, axis_name, data_axis, d, p,
-                    algorithm, capacity, out_capacity, algo_kw, pallas):
-    body = _sort_body(axis_name, p, algorithm, capacity, out_capacity, algo_kw)
-    return comm.sim_map(body, axis_name, p, mesh=(d, p),
-                        data_axis=data_axis)(keys3d, counts)
-
-
-@partial(jax.jit, static_argnames=("cfg", "algorithm", "axis_name",
-                                   "data_axis", "axes", "p", "capacity",
-                                   "out_capacity", "mesh", "algo_kw",
-                                   "pallas"))
-def _psort_nested_jit(keys_nd, counts, mesh, cfg, axis_name, data_axis, axes,
-                      p, algorithm, capacity, out_capacity, algo_kw, pallas):
-    """psort over the virtual flat axis of a nested (inter, intra) mesh.
-
-    The body is the *same* per-PE body as the flat path; its collectives
-    name ``axis_name`` and the :func:`repro.core.comm.nested` scope
-    decomposes them onto the real mesh axes while tracing.  ``data_axis``
-    (when not None) leads for batched keys.
+    ``lead`` holds the leading dimensions of the per-PE arrays — ``(p,)``,
+    ``(d, p)``, ``(p_o, p_i)`` or ``(d, p_o, p_i)`` — and ``names`` the mesh
+    axes in ``lead``'s order; ``axes`` is the nested ``((outer, p_o),
+    (inner, p_i))`` pair or None.  ``algorithm`` is ``"external"`` when the
+    shards stream through the out-of-core lane.  The plan is the device
+    program's jit key: ``n`` is left out of it, since the program reads only
+    ``per``.
     """
-    body = _sort_body(axis_name, p, algorithm, capacity, out_capacity, algo_kw)
-    names = ((data_axis,) if data_axis else ()) + tuple(n for n, _ in axes)
-    nlead = len(names)
+    n: int = dataclasses.field(compare=False)
+    d: int
+    batched: bool
+    p: int
+    per: int
+    lead: tuple
+    names: tuple
+    axes: Optional[tuple]
+    axis: str
+    data_axis: Optional[str]
+    backend: str
+    mesh: Optional[Mesh]
+    algorithm: str
+    capacity: int
+    out_capacity: int
+    algo_kw: tuple
+
+    @property
+    def mesh_shape(self) -> Optional[tuple]:
+        return tuple(s for _, s in self.axes) if self.axes else None
+
+    def body(self):
+        """The per-PE body this plan runs (:func:`_sort_body`)."""
+        return _sort_body(self.axis, self.p, self.algorithm, self.capacity,
+                          self.out_capacity, self.algo_kw)
+
+
+def _plan(shape, cfg: SortConfig) -> _Plan:
+    """Check ``cfg`` against keys of ``shape`` and settle the sort it runs.
+
+    ``psort``, each attempt of the fault lane (its current ``p`` and
+    ``mesh_shape`` set in ``cfg``) and ``trace_collectives`` plan here, so
+    the topology, the algorithm, the capacities and the algorithm keywords
+    are worked out in one place."""
+    p, algorithm, mesh, backend = cfg.p, cfg.algorithm, cfg.mesh, cfg.backend
+    axis, data_axis, mesh_axes = cfg.axis, cfg.data_axis, cfg.mesh_axes
+    mesh_shape, levels, external = cfg.mesh_shape, cfg.levels, cfg.external
+    if levels is not None and algorithm not in ("auto", "rams", "ntb-ams"):
+        raise ValueError(f"levels= applies to the multi-level AMS family "
+                         f"(or 'auto'), not algorithm={algorithm!r}")
+    if len(shape) not in (1, 2):
+        raise ValueError(f"keys must be 1-D (one sort) or 2-D (a batch of "
+                         f"independent sorts); got shape {tuple(shape)}")
+    batched = len(shape) == 2
+    d, n = (shape[0] if batched else 1), shape[-1]
+    axes = None
+    if mesh_shape is not None:
+        p_o, p_i = mesh_shape
+        if (p_o & (p_o - 1)) or (p_i & (p_i - 1)) or p_o < 1 or p_i < 1:
+            raise ValueError(f"mesh_shape={mesh_shape} entries must be "
+                             f"powers of two (hypercube layout)")
+        if p is not None and p != p_o * p_i:
+            raise ValueError(f"p={p} inconsistent with mesh_shape="
+                             f"{mesh_shape}")
+        p = p_o * p_i
+        axes = ((mesh_axes[0], p_o), (mesh_axes[1], p_i))
+        if backend == "shard_map":
+            if mesh is None:
+                from repro.dist.sharding import sort_mesh
+                mesh = sort_mesh(shape=mesh_shape, d=d, data_axis=data_axis,
+                                 mesh_axes=mesh_axes)
+            want = dict(axes)
+            if batched:
+                want[data_axis] = d
+            for a, sz in want.items():
+                if mesh.shape.get(a) != sz:
+                    raise ValueError(f"mesh axis {a!r} must have size {sz}; "
+                                     f"mesh has {dict(mesh.shape)}")
+        elif mesh is not None:
+            raise ValueError("backend='sim' runs meshless; drop the mesh arg")
+    elif backend == "shard_map":
+        if batched:
+            if mesh is None:
+                from repro.dist.sharding import sort_mesh
+                mesh = sort_mesh(p, d=d, axis=axis, data_axis=data_axis)
+            for a in (data_axis, axis):
+                if a not in mesh.shape:
+                    raise ValueError(f"2-D keys need a mesh with axes "
+                                     f"({data_axis!r}, {axis!r}); mesh has "
+                                     f"{tuple(mesh.shape)}")
+            if mesh.shape[data_axis] != d:
+                raise ValueError(f"keys.shape[0]={d} != mesh.shape"
+                                 f"[{data_axis!r}]={mesh.shape[data_axis]}")
+        else:
+            mesh = mesh or default_mesh(p, axis)
+        p = mesh.shape[axis]
+    else:
+        if mesh is not None:
+            raise ValueError("backend='sim' runs meshless; drop the mesh arg")
+        if p is None:
+            raise ValueError("backend='sim' needs an explicit p")
+    if p & (p - 1):
+        raise ValueError(f"p={p} must be a power of two (hypercube layout)")
+    if external is not None:
+        if backend != "sim":
+            raise ValueError("external= requires backend='sim' (host-"
+                             "streamed shards run on emulated PEs)")
+        if batched:
+            raise ValueError("external= supports 1-D keys only (each run "
+                             "pass is one global sort problem)")
+        if mesh_shape is not None:
+            raise ValueError("external= runs on one flat axis; drop "
+                             "mesh_shape")
+    elif algorithm == "external":
+        raise ValueError("algorithm='external' needs external="
+                         "ExternalPolicy(...) (or REPRO_EXTERNAL_BUDGET)")
+    if cfg.fault_policy is not None and backend != "sim":
+        raise ValueError("fault_policy= requires backend='sim' (the "
+                         "fault-injection lane runs on emulated PEs)")
+
+    per = -(-max(n, 1) // p)                       # ceil(n/p)
+    capacity = max(4, int(np.ceil(per * cfg.capacity_factor)))
+    if algorithm == "auto":
+        algorithm = selection.select_algorithm(
+            n, p, model=cfg.cost_model, levels=levels, mesh_shape=mesh_shape,
+            budget=external.budget if external is not None else None)
+    # the shards stream whenever they outgrow the budget: a fault-lane
+    # rescale shrinks p, so an attempt that started in-core can go external
+    # (and never the other way around)
+    if external is not None and (algorithm == "external"
+                                 or per > external.budget):
+        algorithm = "external"
+    algo_kw = dict(cfg.algo_kw)
+    if cfg.overlap and algorithm in _OVERLAP_ALGOS:
+        algo_kw.setdefault("overlap", True)
+    if algorithm in ("rams", "ntb-ams"):
+        if axes is not None:
+            from .rams import nested_level_bits
+            algo_kw.setdefault(
+                "level_bits", tuple(nested_level_bits(p_o, p_i, levels)))
+        elif levels is not None:
+            algo_kw.setdefault("levels", levels)
+    # the gather algorithms concentrate the whole output on PE 0
+    out_capacity = max(1, p * per) if algorithm in ("gatherm", "allgatherm") \
+        else capacity
+    pe_lead = tuple(s for _, s in axes) if axes else (p,)
+    pe_names = tuple(a for a, _ in axes) if axes else (axis,)
+    return _Plan(
+        n=n, d=d, batched=batched, p=p, per=per,
+        lead=((d,) if batched else ()) + pe_lead,
+        names=((data_axis,) if batched else ()) + pe_names,
+        axes=axes, axis=axis, data_axis=data_axis if batched else None,
+        backend=backend, mesh=mesh, algorithm=algorithm, capacity=capacity,
+        out_capacity=out_capacity, algo_kw=tuple(sorted(algo_kw.items())))
+
+
+def _sim_runner(plan: _Plan, impl=None):
+    """The per-PE body over ``plan.lead`` on emulated PEs
+    (:func:`repro.core.comm.sim_map`), its collectives through ``impl``
+    (default: resolved from the ambient scope when it runs)."""
+    return comm.sim_map(plan.body(), plan.axis, plan.p, impl=impl,
+                        nested=plan.axes,
+                        mesh=(plan.d, plan.p) if plan.batched else None,
+                        data_axis=plan.data_axis)
+
+
+@partial(jax.jit, static_argnames=("plan", "pallas"))
+def _device_program(keys_nd, counts_nd, *, plan: _Plan, pallas):
+    """psort's device program: the per-PE body run by ``shard_map`` over
+    the mesh axes ``plan.names`` (one PE a block), or by :func:`_sim_runner`
+    on the sim backend.
+
+    On a nested mesh the body's collectives name the virtual ``plan.axis``
+    and the :func:`repro.core.comm.nested` scope decomposes them onto the
+    real axes while tracing.  ``pallas`` (the local-kernel policy) is a
+    cache key only: the policy is read at trace time, so without it a
+    cached executable would silently ignore a toggle between calls."""
+    if plan.backend == "sim":
+        return _sim_runner(plan)(keys_nd, counts_nd)
+    body = plan.body()
+    nlead = len(plan.lead)
 
     def blk(keys_blk, count_blk):
-        with comm.nested(axis_name, axes):
-            k, i, c, o = body(keys_blk.reshape(keys_blk.shape[nlead:]),
-                              count_blk.reshape(()))
-        dims = tuple(range(nlead))
-        return tuple(jnp.expand_dims(v, dims) for v in (k, i, c, o))
+        with (comm.nested(plan.axis, plan.axes) if plan.axes
+              else contextlib.nullcontext()):
+            outs = body(keys_blk.reshape(keys_blk.shape[nlead:]),
+                        count_blk.reshape(()))
+        return tuple(jnp.expand_dims(v, tuple(range(nlead))) for v in outs)
 
-    out = jax.shard_map(blk, mesh=mesh,
-                        in_specs=(P(*names), P(*names)),
-                        out_specs=(P(*names),) * 4,
-                        check_vma=False)(keys_nd, counts)
-    return out
+    spec = P(*plan.names)
+    return jax.shard_map(blk, mesh=plan.mesh, in_specs=(spec, spec),
+                         out_specs=(spec,) * 4, check_vma=False)(keys_nd,
+                                                                 counts_nd)
 
 
-@partial(jax.jit, static_argnames=("cfg", "algorithm", "axis_name",
-                                   "data_axis", "d", "axes", "p", "capacity",
-                                   "out_capacity", "algo_kw", "pallas"))
-def _psort_nested_sim_jit(keys_nd, counts, cfg, axis_name, data_axis, d, axes,
-                          p, algorithm, capacity, out_capacity, algo_kw,
-                          pallas):
-    body = _sort_body(axis_name, p, algorithm, capacity, out_capacity, algo_kw)
-    return comm.sim_map(body, axis_name, p, nested=axes,
-                        mesh=(d, p) if data_axis else None,
-                        data_axis=data_axis)(keys_nd, counts)
+def _layout(u, plan: _Plan):
+    """The device program's inputs: ``u`` padded to p·per keys a row and
+    shaped ``plan.lead + (per,)``, and each PE's count of real keys."""
+    p, per, n = plan.p, plan.per, plan.n
+    row_counts = jnp.minimum(jnp.maximum(n - per * jnp.arange(p), 0),
+                             per).astype(jnp.int32)
+    flat = jnp.full(u.shape[:-1] + (p * per,), pad_value(u.dtype), u.dtype)
+    flat = flat.at[..., :n].set(u)
+    pe_lead = plan.lead[1:] if plan.batched else plan.lead
+    return (flat.reshape(plan.lead + (per,)),
+            jnp.broadcast_to(row_counts.reshape(pe_lead), plan.lead))
+
+
+def _pull(outs, plan: _Plan):
+    """The device program's outputs on the host, shaped ``(d, p, ...)``."""
+    return [np.asarray(o).reshape((plan.d, plan.p) + o.shape[len(plan.lead):])
+            for o in outs]
+
+
+def _answer_pes(plan: _Plan) -> range:
+    """The PEs whose outputs make up the answer: allgatherm leaves the
+    whole answer on every PE, so PE 0's alone."""
+    return range(1) if plan.algorithm == "allgatherm" else range(plan.p)
 
 
 def psort(keys, config=None, *, return_info: bool = False, **legacy):
@@ -484,482 +606,180 @@ def psort(keys, config=None, *, return_info: bool = False, **legacy):
     """
     with jax.profiler.TraceAnnotation("psort"):
         with jax.profiler.TraceAnnotation("psort.prepare"):
-            cfg = _coerce_config(config, legacy, caller="psort")
-            launched = _launch(keys, cfg, return_info)
-        if not isinstance(launched, _Launched):
-            return launched()              # the fault-policy or external path
-        return _collect(launched, return_info)
+            cfg = _resolve_external(
+                _coerce_config(config, legacy, caller="psort"))
+            keys = jnp.asarray(keys)
+            plan = _plan(keys.shape, cfg)
+            u = key_to_uint(keys)
+            in_core = cfg.fault_policy is None and plan.algorithm != "external"
+            if in_core:
+                outs = _device_program(*_layout(u, plan), plan=plan,
+                                       pallas=local_kernels())
+        if cfg.fault_policy is not None:
+            return _psort_faulty(u, keys.dtype, cfg, plan, return_info)
+        if not in_core:
+            return _psort_external(u, keys.dtype, cfg, plan, return_info)
+        return _collect(outs, plan, keys.dtype, return_info)
 
 
-class _Launched(NamedTuple):
-    """An in-core sort whose device program is dispatched: its padded
-    per-PE outputs, shaped ``(d, p, ...)``, and what assembling them needs."""
-    keys: jax.Array
-    idx: jax.Array
-    counts: jax.Array
-    overflow: jax.Array
-    n: int
-    batched: bool
-    algorithm: str
-    orig_dtype: np.dtype
-    cfg: SortConfig
-
-
-def _launch(keys, cfg: SortConfig, return_info: bool):
-    """``psort`` up to the return of the jitted device program.
-
-    Returns a :class:`_Launched`, or, for the fault-policy and external
-    paths, a callable that runs the rest of the sort and returns ``psort``'s
-    result."""
-    p, algorithm, mesh = cfg.p, cfg.algorithm, cfg.mesh
-    axis, data_axis = cfg.axis, cfg.data_axis
-    mesh_shape, mesh_axes, levels = cfg.mesh_shape, cfg.mesh_axes, cfg.levels
-    capacity_factor, backend = cfg.capacity_factor, cfg.backend
-    cost_model, fault_policy = cfg.cost_model, cfg.fault_policy
-    external = cfg.external
-    algo_kw = dict(cfg.algo_kw)
-    if levels is not None and algorithm not in ("auto", "rams", "ntb-ams"):
-        raise ValueError(f"levels= applies to the multi-level AMS family "
-                         f"(or 'auto'), not algorithm={algorithm!r}")
-    keys = jnp.asarray(keys)
-    if keys.ndim not in (1, 2):
-        raise ValueError(f"keys must be 1-D (one sort) or 2-D (a batch of "
-                         f"independent sorts); got shape {keys.shape}")
-    batched = keys.ndim == 2
-    d = keys.shape[0] if batched else 1
-    if mesh_shape is not None:
-        p_o, p_i = (int(v) for v in mesh_shape)
-        if (p_o & (p_o - 1)) or (p_i & (p_i - 1)) or p_o < 1 or p_i < 1:
-            raise ValueError(f"mesh_shape={mesh_shape} entries must be "
-                             f"powers of two (hypercube layout)")
-        if p is not None and p != p_o * p_i:
-            raise ValueError(f"p={p} inconsistent with mesh_shape="
-                             f"{tuple(mesh_shape)}")
-        p = p_o * p_i
-        if backend == "shard_map":
-            if mesh is None:
-                from repro.dist.sharding import sort_mesh
-                mesh = sort_mesh(shape=(p_o, p_i), d=d if batched else 1,
-                                 data_axis=data_axis, mesh_axes=mesh_axes)
-            want = dict(zip(mesh_axes, (p_o, p_i)))
-            if batched:
-                want[data_axis] = d
-            for a, sz in want.items():
-                if mesh.shape.get(a) != sz:
-                    raise ValueError(f"mesh axis {a!r} must have size {sz}; "
-                                     f"mesh has {dict(mesh.shape)}")
-        elif mesh is not None:
-            raise ValueError("backend='sim' runs meshless; drop the mesh arg")
-    elif backend == "shard_map":
-        if batched:
-            if mesh is None:
-                from repro.dist.sharding import sort_mesh
-                mesh = sort_mesh(p, d=d, axis=axis, data_axis=data_axis)
-            for a in (data_axis, axis):
-                if a not in mesh.shape:
-                    raise ValueError(f"2-D keys need a mesh with axes "
-                                     f"({data_axis!r}, {axis!r}); mesh has "
-                                     f"{tuple(mesh.shape)}")
-            if mesh.shape[data_axis] != d:
-                raise ValueError(f"keys.shape[0]={d} != mesh.shape"
-                                 f"[{data_axis!r}]={mesh.shape[data_axis]}")
-        else:
-            mesh = mesh or default_mesh(p, axis)
-        p = mesh.shape[axis]
-    else:
-        if mesh is not None:
-            raise ValueError("backend='sim' runs meshless; drop the mesh arg")
-        if p is None:
-            raise ValueError("backend='sim' needs an explicit p")
-    if p & (p - 1):
-        raise ValueError(f"p={p} must be a power of two (hypercube layout)")
-    n = keys.shape[-1]
-    orig_dtype = keys.dtype
-    u = key_to_uint(keys)
-
-    external = _resolve_external(external, backend)
-    if external is not None:
-        if backend != "sim":
-            raise ValueError("external= requires backend='sim' (host-"
-                             "streamed shards run on emulated PEs)")
-        if batched:
-            raise ValueError("external= supports 1-D keys only (each run "
-                             "pass is one global sort problem)")
-        if mesh_shape is not None:
-            raise ValueError("external= runs on one flat axis; drop "
-                             "mesh_shape")
-    elif algorithm == "external":
-        raise ValueError("algorithm='external' needs external="
-                         "ExternalPolicy(...) (or REPRO_EXTERNAL_BUDGET)")
-
-    if fault_policy is not None:
-        if backend != "sim":
-            raise ValueError("fault_policy= requires backend='sim' (the "
-                             "fault-injection lane runs on emulated PEs)")
-        return partial(
-            _psort_faulty,
-            u, n, d, batched, orig_dtype, p=p, algorithm=algorithm,
-            policy=fault_policy, axis=axis, data_axis=data_axis,
-            mesh_shape=(p_o, p_i) if mesh_shape is not None else None,
-            mesh_axes=mesh_axes, levels=levels,
-            capacity_factor=capacity_factor, return_info=return_info,
-            cost_model=cost_model, algo_kw=algo_kw, external=external,
-            overlap=cfg.overlap)
-
-    per = -(-max(n, 1) // p)                       # ceil(n/p)
-    capacity = max(4, int(np.ceil(per * capacity_factor)))
-    if algorithm == "auto":
-        algorithm = selection.select_algorithm(
-            n, p, model=cost_model, levels=levels, mesh_shape=mesh_shape,
-            budget=external.budget if external is not None else None)
-    if external is not None and (algorithm == "external"
-                                 or per > external.budget):
-        return partial(_psort_external, u, n, orig_dtype, p=p, axis=axis,
-                       policy=external, return_info=return_info,
-                       overlap=cfg.overlap)
-    if cfg.overlap and algorithm in _OVERLAP_ALGOS:
-        algo_kw.setdefault("overlap", True)
-    if algorithm in ("rams", "ntb-ams"):
-        if mesh_shape is not None:
-            from .rams import nested_level_bits
-            algo_kw.setdefault(
-                "level_bits", tuple(nested_level_bits(p_o, p_i, levels)))
-        elif levels is not None:
-            algo_kw.setdefault("levels", levels)
-    out_capacity = _out_capacity(algorithm, n, p, per, capacity)
-
-    pad = pad_value(u.dtype)
-    row_counts = jnp.minimum(jnp.maximum(n - per * jnp.arange(p), 0),
-                             per).astype(jnp.int32)
-    kw = tuple(sorted(algo_kw.items()))
-    # jit caches key on the local-kernel policy: the policy is read at
-    # trace time, so without this a cached executable would silently
-    # ignore a toggle between calls of the same signature.
-    pl = local_kernels()
-    if mesh_shape is not None:
-        axes = ((mesh_axes[0], p_o), (mesh_axes[1], p_i))
-        lead = (d,) if batched else ()
-        flat = jnp.full(lead + (p * per,), pad, u.dtype)
-        flat = flat.at[..., :n].set(u)
-        keys_nd = flat.reshape(lead + (p_o, p_i, per))
-        counts_nd = jnp.broadcast_to(row_counts.reshape(p_o, p_i),
-                                     lead + (p_o, p_i))
-        da = data_axis if batched else None
-        if backend == "shard_map":
-            keys_out, idx_out, counts_out, overflow = _psort_nested_jit(
-                keys_nd, counts_nd, mesh, cfg, axis, da, axes, p, algorithm,
-                capacity, out_capacity, kw, pallas=pl)
-        else:
-            keys_out, idx_out, counts_out, overflow = _psort_nested_sim_jit(
-                keys_nd, counts_nd, cfg, axis, da, d, axes, p, algorithm,
-                capacity, out_capacity, kw, pallas=pl)
-        keys_out = keys_out.reshape((d, p) + keys_out.shape[-1:])
-        idx_out = idx_out.reshape((d, p) + idx_out.shape[-1:])
-        counts_out = counts_out.reshape(d, p)
-        overflow = overflow.reshape(d, p)
-    elif batched:
-        flat = jnp.full((d, p * per), pad, u.dtype).at[:, :n].set(u)
-        keys3d = flat.reshape(d, p, per)
-        counts = jnp.broadcast_to(row_counts, (d, p))
-        if backend == "shard_map":
-            keys_out, idx_out, counts_out, overflow = _psort2_jit(
-                keys3d, counts, mesh, cfg, axis, data_axis, p, algorithm,
-                capacity, out_capacity, kw, pallas=pl)
-        else:
-            keys_out, idx_out, counts_out, overflow = _psort2_sim_jit(
-                keys3d, counts, cfg, axis, data_axis, d, p, algorithm,
-                capacity, out_capacity, kw, pallas=pl)
-    else:
-        flat = jnp.full((p * per,), pad, u.dtype).at[:n].set(u)
-        keys2d = flat.reshape(p, per)
-        if backend == "shard_map":
-            keys_out, idx_out, counts_out, overflow = _psort_jit(
-                keys2d, row_counts, mesh, cfg, axis, p, algorithm, capacity,
-                out_capacity, kw, pallas=pl)
-        else:
-            keys_out, idx_out, counts_out, overflow = _psort_sim_jit(
-                keys2d, row_counts, cfg, axis, p, algorithm, capacity,
-                out_capacity, kw, pallas=pl)
-        keys_out, idx_out = keys_out[None], idx_out[None]
-        counts_out, overflow = counts_out[None], overflow[None]
-
-    return _Launched(keys_out, idx_out, counts_out, overflow, n, batched,
-                     algorithm, orig_dtype, cfg)
-
-
-def _collect(job: _Launched, return_info: bool):
-    """The in-core layouts' shared tail: wait for the device program, pull
-    its padded per-PE outputs and assemble the answer from them."""
-    outs = (job.keys, job.counts) + ((job.idx, job.overflow)
-                                     if return_info else ())
+def _collect(outs, plan: _Plan, orig_dtype, return_info: bool):
+    """The in-core tail: wait for the device program, pull its padded
+    per-PE outputs and assemble the answer from them."""
+    keys, idx, counts, overflow = outs
+    outs = (keys, counts) + ((idx, overflow) if return_info else ())
     with jax.profiler.TraceAnnotation("psort.wait"):
         jax.block_until_ready(outs)
     with jax.profiler.TraceAnnotation("psort.pull",
                                       bytes=sum(o.nbytes for o in outs)):
-        outs = [np.asarray(o) for o in outs]
-    keys_out, counts_out = outs[:2]                # (d, p, out_capacity), (d, p)
-    n, (d, p) = job.n, counts_out.shape
-    pe_range = range(1) if job.algorithm == "allgatherm" else range(p)
-    answer_bytes = int(counts_out[:, pe_range].sum()) \
-        * np.dtype(job.orig_dtype).itemsize
+        outs = _pull(outs, plan)
+    keys, counts = outs[:2]
+    idx, overflow = outs[2:] if return_info else (None, None)
+    answer_bytes = int(counts[:, _answer_pes(plan)].sum()) \
+        * np.dtype(orig_dtype).itemsize
     with jax.profiler.TraceAnnotation("psort.assemble", bytes=answer_bytes):
-        rows = [np.concatenate([keys_out[r, i, :counts_out[r, i]]
-                                for i in pe_range]) for r in range(d)]
-        result = uint_to_key(
-            jnp.asarray(np.stack(rows) if job.batched else rows[0]),
-            job.orig_dtype)
-        if not return_info:
-            return result
-        idx_out, overflow = outs[2:]
-        perms = [np.concatenate([idx_out[r, i, :counts_out[r, i]]
-                                 for i in range(p)]) if n
-                 else np.zeros((0,), np.uint32) for r in range(d)]
-        info = {
-            "algorithm": job.algorithm,
-            "backend": job.cfg.backend,
-            "mesh_shape": job.cfg.mesh_shape,
-            "counts": counts_out if job.batched else counts_out[0],
-            "overflow": int(overflow.sum()),
-            "balance": counts_out.max() / max(1.0, n / p),
-            "perm": np.stack(perms) if job.batched else perms[0],
-            "n": n,
-            "d": d,
-        }
-        return result, info
+        return _assemble(keys, idx, counts, overflow, plan, orig_dtype,
+                         return_info)
 
 
-def _out_capacity(algorithm: str, n: int, p: int, per: int, capacity: int) -> int:
-    if algorithm in ("gatherm", "allgatherm"):
-        return max(1, p * per)                     # concentrated output
-    return capacity
+def _assemble(keys, idx, counts, overflow, plan: _Plan, orig_dtype,
+              return_info: bool, extra_info=None):
+    """``psort``'s result from the host outputs of every path — keys and
+    idx ``(d, p, out_cap)``, counts and overflow ``(d, p)`` — and, with
+    ``return_info``, its info dict, ``extra_info`` added."""
+    n, d, p = plan.n, plan.d, plan.p
+    rows = [np.concatenate([keys[r, i, :counts[r, i]]
+                            for i in _answer_pes(plan)]) for r in range(d)]
+    result = uint_to_key(jnp.asarray(np.stack(rows) if plan.batched
+                                     else rows[0]), orig_dtype)
+    if not return_info:
+        return result
+    perms = [np.concatenate([idx[r, i, :counts[r, i]] for i in range(p)])
+             if n else np.zeros((0,), np.uint32) for r in range(d)]
+    info = {
+        "algorithm": plan.algorithm,
+        "backend": plan.backend,
+        "mesh_shape": plan.mesh_shape,
+        "counts": counts if plan.batched else counts[0],
+        "overflow": int(np.asarray(overflow).sum()),
+        "balance": counts.max() / max(1.0, n / p),
+        "perm": np.stack(perms) if plan.batched else perms[0],
+        "n": n,
+        "d": d,
+        **(extra_info or {}),
+    }
+    return result, info
 
 
-def _resolve_external(external, backend: str):
+def _resolve_external(cfg: SortConfig) -> SortConfig:
     """Explicit policy wins; else ``REPRO_EXTERNAL_BUDGET`` (sim only)."""
-    if external is not None:
-        return external
     env = os.environ.get("REPRO_EXTERNAL_BUDGET")
-    if env and backend == "sim":
-        from .external import ExternalPolicy
-        return ExternalPolicy(budget=int(env))
-    return None
+    if cfg.external is not None or not env or cfg.backend != "sim":
+        return cfg
+    from .external import ExternalPolicy
+    return cfg.replace(external=ExternalPolicy(budget=int(env)))
 
 
-def _psort_external(u, n, orig_dtype, *, p, axis, policy, return_info,
-                    overlap=False):
+def _psort_external(u, orig_dtype, cfg: SortConfig, plan: _Plan,
+                    return_info: bool):
     """The non-fault ``psort(..., external=...)`` tail: run the four
-    external passes once and reassemble the host output exactly like the
-    in-core paths.  Ambient collectives decorators (``comm.counting()``)
-    apply — the passes resolve ``impl`` per ``sim_map`` call."""
+    external passes once and assemble the answer like the in-core path.
+    Ambient collectives decorators (``comm.counting()``) apply — the passes
+    resolve ``impl`` per ``sim_map`` call."""
     from .external import _psort_external_once
-    keys_out, idx_out, counts_out, overflow = _psort_external_once(
-        u, n, axis=axis, p=p, policy=policy, impl=None, overlap=overlap)
-    rows = np.concatenate([keys_out[0, pe, :counts_out[0, pe]]
-                           for pe in range(p)])
-    result = uint_to_key(jnp.asarray(rows), orig_dtype)
-    if return_info:
-        per = -(-max(n, 1) // p)
-        perm = (np.concatenate([idx_out[0, pe, :counts_out[0, pe]]
-                                for pe in range(p)]) if n
-                else np.zeros((0,), np.uint32))
-        info = {
-            "algorithm": "external",
-            "backend": "sim",
-            "mesh_shape": None,
-            "counts": counts_out[0],
-            "overflow": int(np.asarray(overflow).sum()),
-            "balance": counts_out.max() / max(1.0, n / p),
-            "perm": perm,
-            "n": n,
-            "d": 1,
-            "external": {
-                "budget": policy.budget,
-                "runs": max(1, -(-per // policy.budget)),
-                "merge": policy.merge,
-            },
-        }
-        return result, info
-    return result
+    policy = cfg.external
+    outs = _psort_external_once(u, plan.n, axis=plan.axis, p=plan.p,
+                                policy=policy, impl=None, overlap=cfg.overlap)
+    return _assemble(*outs, plan, orig_dtype, return_info, {"external": {
+        "budget": policy.budget,
+        "runs": max(1, -(-plan.per // policy.budget)),
+        "merge": policy.merge,
+    }})
 
 
-def _psort_sim_once(u, n, d, batched, *, axis, data_axis, p, mesh_shape,
-                    mesh_axes, algorithm, capacity_factor, levels, algo_kw,
-                    impl):
-    """One sim-backend sort attempt at a fixed topology under ``impl``.
-
-    The fault lane's executor: pads/redistributes the full key array over
-    the *current* p, builds the per-PE body, and runs it under a **fresh**
-    ``jax.jit`` — injection and counting act at trace time, so the cached
-    module-level jits (which would replay nothing on a cache hit) cannot
-    be used here.  Returns host arrays ``(keys, idx, counts, overflow)``
-    of shapes ``(d, p, out_cap) ×2, (d, p) ×2``.
-    """
-    per = -(-max(n, 1) // p)
-    capacity = max(4, int(np.ceil(per * capacity_factor)))
-    kw = dict(algo_kw)
-    if algorithm in ("rams", "ntb-ams"):
-        if mesh_shape is not None:
-            from .rams import nested_level_bits
-            kw.setdefault("level_bits", tuple(nested_level_bits(
-                mesh_shape[0], mesh_shape[1], levels)))
-        elif levels is not None:
-            kw.setdefault("levels", levels)
-    out_capacity = _out_capacity(algorithm, n, p, per, capacity)
-    body = _sort_body(axis, p, algorithm, capacity, out_capacity,
-                      tuple(sorted(kw.items())))
-    pad = pad_value(u.dtype)
-    row_counts = jnp.minimum(jnp.maximum(n - per * jnp.arange(p), 0),
-                             per).astype(jnp.int32)
-    lead = (d,) if batched else ()
-    flat = jnp.full(lead + (p * per,), pad, u.dtype)
-    flat = flat.at[..., :n].set(u)
-    da = data_axis if batched else None
-    if mesh_shape is not None:
-        p_o, p_i = mesh_shape
-        axes = ((mesh_axes[0], p_o), (mesh_axes[1], p_i))
-        keys_nd = flat.reshape(lead + (p_o, p_i, per))
-        counts_nd = jnp.broadcast_to(row_counts.reshape(p_o, p_i),
-                                     lead + (p_o, p_i))
-        runner = comm.sim_map(body, axis, p, impl=impl, nested=axes,
-                              mesh=(d, p) if batched else None, data_axis=da)
-        k, i, c, o = jax.jit(runner)(keys_nd, counts_nd)
-        k = k.reshape((d, p) + k.shape[-1:])
-        i = i.reshape((d, p) + i.shape[-1:])
-        c, o = c.reshape(d, p), o.reshape(d, p)
-    elif batched:
-        runner = comm.sim_map(body, axis, p, impl=impl, mesh=(d, p),
-                              data_axis=da)
-        k, i, c, o = jax.jit(runner)(flat.reshape(d, p, per),
-                                     jnp.broadcast_to(row_counts, (d, p)))
-    else:
-        runner = comm.sim_map(body, axis, p, impl=impl)
-        k, i, c, o = jax.jit(runner)(flat.reshape(p, per), row_counts)
-        k, i, c, o = k[None], i[None], c[None], o[None]
-    return np.asarray(k), np.asarray(i), np.asarray(c), np.asarray(o)
-
-
-def _psort_faulty(u, n, d, batched, orig_dtype, *, p, algorithm, policy,
-                  axis, data_axis, mesh_shape, mesh_axes, levels,
-                  capacity_factor, return_info, cost_model, algo_kw,
-                  external=None, overlap=False):
+def _psort_faulty(u, orig_dtype, cfg: SortConfig, plan: _Plan,
+                  return_info: bool):
     """The ``psort(..., fault_policy=...)`` driver (sim backend).
 
     Attempt loop (bounded by ``repro.runtime.failures.run_with_restarts``):
-    trace the sort afresh under ``FaultyCollectives`` executing the
-    policy's surviving :class:`repro.core.comm.FaultPlan`; on a
-    :class:`repro.core.comm.PEFailure` — raised by a fired kill, or by
-    this driver for a watchdog-flagged straggler — exclude the PE, plan
-    the reduced topology (``elastic.plan_sort_rescale``), record a
-    ``rescale`` trace event carrying the new extent, and retry.  Progress
-    = shrinking p, so a rescale that fails to shrink trips the loop's
-    no-progress give-up rather than burning the restart budget.
+    plan the sort at the current topology and trace it afresh under
+    ``FaultyCollectives`` executing the policy's surviving
+    :class:`repro.core.comm.FaultPlan` — a fresh ``jax.jit`` each time,
+    since injection and counting act at trace time and a cached executable
+    would replay nothing.  On a :class:`repro.core.comm.PEFailure` — raised
+    by a fired kill, or by this function for a watchdog-flagged straggler —
+    exclude the PE, plan the reduced topology
+    (``elastic.plan_sort_rescale``), record a ``rescale`` trace event
+    carrying the new extent, and retry.  Progress = shrinking p, so a
+    rescale that fails to shrink trips the loop's no-progress give-up
+    rather than burning the restart budget.
     """
     from repro.runtime.elastic import plan_sort_rescale
     from repro.runtime.failures import flag_stragglers, run_with_restarts
 
+    policy = cfg.fault_policy
     trace = policy.trace if policy.trace is not None else comm.CommTrace()
     policy.trace = trace
     log = policy.logger if policy.logger is not None else (lambda *a: None)
-    plan0 = policy.plan if policy.plan is not None else comm.FaultPlan()
-    if not isinstance(plan0, comm.FaultPlan):
-        plan0 = comm.FaultPlan(tuple(plan0))
-    state = {"p": p, "mesh_shape": mesh_shape, "plan": plan0,
+    faults = policy.plan if policy.plan is not None else comm.FaultPlan()
+    if not isinstance(faults, comm.FaultPlan):
+        faults = comm.FaultPlan(tuple(faults))
+    state = {"p": plan.p, "mesh_shape": plan.mesh_shape, "faults": faults,
              "failed": ()}
     policy.attempts.clear()
 
     def attempt(_start):
-        p_cur, ms = state["p"], state["mesh_shape"]
-        per_cur = -(-max(n, 1) // p_cur)
-        algo = algorithm
-        if algo == "auto":
-            algo = selection.select_algorithm(
-                n, p_cur, model=cost_model, levels=levels, mesh_shape=ms,
-                budget=external.budget if external is not None else None)
-        # external engages whenever the per-PE shard outgrows the budget —
-        # a rescale shrinks p, so an attempt that started in-core can go
-        # external after exclusion (and never the other way around)
-        ext = external is not None and (algo == "external"
-                                        or per_cur > external.budget)
-        if ext:
-            algo = "external"
-        rec = {"p": p_cur, "mesh_shape": ms, "algorithm": algo, "ok": False}
+        # algorithm="auto" is re-selected at the attempt's p, and overlap
+        # applies to what that picks
+        att = _plan(u.shape, cfg.replace(p=state["p"],
+                                         mesh_shape=state["mesh_shape"]))
+        rec = {"p": att.p, "mesh_shape": att.mesh_shape,
+               "algorithm": att.algorithm, "ok": False}
         policy.attempts.append(rec)
         # faulty outside counting: a killed launch records its fault:kill
         # event but not the launch the dead PE never completed
         fc = comm.FaultyCollectives(
-            comm.CountingCollectives(comm.SIM, trace), state["plan"], trace)
-        if ext:
+            comm.CountingCollectives(comm.SIM, trace), state["faults"], trace)
+        if att.algorithm == "external":
             from .external import _psort_external_once
-            out = _psort_external_once(u, n, axis=axis, p=p_cur,
-                                       policy=external, impl=fc,
-                                       overlap=overlap)
+            out = _psort_external_once(u, att.n, axis=att.axis, p=att.p,
+                                       policy=cfg.external, impl=fc,
+                                       overlap=cfg.overlap)
         else:
-            # overlap applies per attempt: the re-selected algorithm at the
-            # reduced p may or may not have a streamable exchange
-            kw_att = dict(algo_kw)
-            if overlap and algo in _OVERLAP_ALGOS:
-                kw_att.setdefault("overlap", True)
-            out = _psort_sim_once(
-                u, n, d, batched, axis=axis, data_axis=data_axis, p=p_cur,
-                mesh_shape=ms, mesh_axes=mesh_axes, algorithm=algo,
-                capacity_factor=capacity_factor, levels=levels,
-                algo_kw=kw_att, impl=fc)
+            out = _pull(jax.jit(_sim_runner(att, fc))(*_layout(u, att)), att)
         times = [policy.base_step_time * fc.fired_delays.get(pe, 1.0)
-                 for pe in range(p_cur)]
+                 for pe in range(att.p)]
         slow = flag_stragglers(times, k_mad=policy.k_mad,
                                warmup=policy.warmup)
         if slow:
             raise comm.PEFailure(slow[0], phase="straggler")
         rec["ok"] = True
-        return out + (p_cur, ms, algo)
+        return out, att
 
     def rescale(e, restarts):
         p_cur, ms = state["p"], state["mesh_shape"]
         rplan = plan_sort_rescale(p_cur, (e.pe,), mesh_shape=ms)
-        trace.add("rescale", 0, rplan.p_new, axis=axis, tag=e.phase,
+        trace.add("rescale", 0, rplan.p_new, axis=plan.axis, tag=e.phase,
                   pe=e.pe)
         why = "straggling" if e.phase == "straggler" else "failed"
         log(f"[psort] PE {e.pe} {why} at p={p_cur}; "
             f"rescaling to p={rplan.p_new}")
         state["p"] = rplan.p_new
         state["mesh_shape"] = rplan.mesh_shape
-        state["plan"] = state["plan"].surviving(e.pe, rplan.p_new)
+        state["faults"] = state["faults"].surviving(e.pe, rplan.p_new)
         state["failed"] += (e.pe,)
 
-    keys_out, idx_out, counts_out, overflow, p_fin, ms_fin, algo_fin = \
-        run_with_restarts(attempt, max_restarts=policy.max_restarts,
-                          retry_on=(comm.PEFailure,), on_failure=rescale,
-                          progress_fn=lambda: -state["p"], logger=log)
-
-    pe_range = range(1) if algo_fin == "allgatherm" else range(p_fin)
-    rows = [np.concatenate([keys_out[r, i, :counts_out[r, i]]
-                            for i in pe_range]) for r in range(d)]
-    result = uint_to_key(jnp.asarray(np.stack(rows) if batched else rows[0]),
-                         orig_dtype)
-    if return_info:
-        perms = [np.concatenate([idx_out[r, i, :counts_out[r, i]]
-                                 for i in range(p_fin)]) if n
-                 else np.zeros((0,), np.uint32) for r in range(d)]
-        info = {
-            "algorithm": algo_fin,
-            "backend": "sim",
-            "mesh_shape": ms_fin,
-            "counts": counts_out if batched else counts_out[0],
-            "overflow": int(np.asarray(overflow).sum()),
-            "balance": counts_out.max() / max(1.0, n / p_fin),
-            "perm": np.stack(perms) if batched else perms[0],
-            "n": n,
-            "d": d,
-            "fault": {
-                "p_final": p_fin,
-                "failed": state["failed"],
-                "restarts": len(policy.attempts) - 1,
-                "attempts": list(policy.attempts),
-            },
-            "comm_trace": trace,
-        }
-        return result, info
-    return result
+    out, fin = run_with_restarts(
+        attempt, max_restarts=policy.max_restarts, retry_on=(comm.PEFailure,),
+        on_failure=rescale, progress_fn=lambda: -state["p"], logger=log)
+    return _assemble(*out, fin, orig_dtype, return_info, {
+        "fault": {
+            "p_final": fin.p,
+            "failed": state["failed"],
+            "restarts": len(policy.attempts) - 1,
+            "attempts": list(policy.attempts),
+        },
+        "comm_trace": trace,
+    })
 
 
 def trace_collectives(n: int, config=None, *args, d: int = 1,
@@ -1035,62 +855,21 @@ def trace_collectives(n: int, config=None, *args, d: int = 1,
                             f"after n/p ({names}); got {len(args)}")
         legacy.update(zip(names, args))
     cfg = _coerce_config(config, legacy, caller="trace_collectives")
-    p, algorithm = cfg.p, cfg.algorithm
-    capacity_factor, levels = cfg.capacity_factor, cfg.levels
-    mesh_shape, mesh_axes = cfg.mesh_shape, cfg.mesh_axes
-    external = cfg.external
-    algo_kw = dict(cfg.algo_kw)
-    if external is not None:
-        if d > 1 or mesh_shape is not None:
-            raise ValueError("external tracing covers the 1-D flat axis "
-                             "only (the external lane's contract)")
-        if p is None or p & (p - 1):
-            raise ValueError(f"p={p} must be a power of two")
+    # the trace runs on emulated PEs whatever backend the config names
+    plan = _plan((d, n) if d > 1 else (n,),
+                 cfg.replace(backend="sim", mesh=None))
+    counter = comm.CountingCollectives(comm.SIM)
+    if cfg.external is not None:
         from .external import _psort_external_once
         rng = np.random.default_rng(0xE87)
         u = jnp.asarray(rng.integers(0, 2 ** 32, size=max(n, 1),
                                      dtype=np.int64).astype(np.uint32))
-        counter = comm.CountingCollectives(comm.SIM)
-        _psort_external_once(u, n, axis="sort", p=p, policy=external,
-                             impl=counter, overlap=cfg.overlap)
-        return counter.trace
-    axes = None
-    if mesh_shape is not None:
-        p_o, p_i = (int(v) for v in mesh_shape)
-        if p is not None and p != p_o * p_i:
-            raise ValueError(f"p={p} inconsistent with mesh_shape="
-                             f"{tuple(mesh_shape)}")
-        p = p_o * p_i
-        axes = ((mesh_axes[0], p_o), (mesh_axes[1], p_i))
-    if p is None:
-        raise ValueError("trace_collectives needs p or mesh_shape")
-    if p & (p - 1):
-        raise ValueError(f"p={p} must be a power of two (hypercube layout)")
-    if algorithm == "auto":
-        algorithm = selection.select_algorithm(n, p, model=cfg.cost_model,
-                                               levels=levels,
-                                               mesh_shape=mesh_shape)
-    if cfg.overlap and algorithm in _OVERLAP_ALGOS:
-        algo_kw.setdefault("overlap", True)
-    if algorithm in ("rams", "ntb-ams"):
-        if mesh_shape is not None:
-            from .rams import nested_level_bits
-            algo_kw.setdefault(
-                "level_bits", tuple(nested_level_bits(p_o, p_i, levels)))
-        elif levels is not None:
-            algo_kw.setdefault("levels", levels)
-    per = -(-max(n, 1) // p)
-    capacity = max(4, int(np.ceil(per * capacity_factor)))
-    out_capacity = _out_capacity(algorithm, n, p, per, capacity)
-    body = _sort_body("sort", p, algorithm, capacity, out_capacity,
-                      tuple(sorted(algo_kw.items())))
-    counter = comm.CountingCollectives(comm.SIM)
-    mesh = (d, p) if d > 1 else None
-    runner = comm.sim_map(body, "sort", p, impl=counter, mesh=mesh,
-                          data_axis="data" if d > 1 else None, nested=axes)
-    axis_lead = (p_o, p_i) if axes is not None else (p,)
-    lead = ((d,) + axis_lead) if d > 1 else axis_lead
-    jax.eval_shape(runner,
-                   jax.ShapeDtypeStruct(lead + (per,), jnp.uint32),
-                   jax.ShapeDtypeStruct(lead, jnp.int32))
+        _psort_external_once(u, n, axis=plan.axis, p=plan.p,
+                             policy=cfg.external, impl=counter,
+                             overlap=cfg.overlap)
+    else:
+        jax.eval_shape(_sim_runner(plan, impl=counter),
+                       jax.ShapeDtypeStruct(plan.lead + (plan.per,),
+                                            jnp.uint32),
+                       jax.ShapeDtypeStruct(plan.lead, jnp.int32))
     return counter.trace

@@ -493,7 +493,7 @@ def _psort_external_once(u, n: int, *, axis: str, p: int,
 
     ``u`` is the full uint key array (host or device); returns host
     ``(keys (1, p, out_cap), idx (1, p, out_cap), counts (1, p),
-    overflow (1, p))`` — the same contract as ``_psort_sim_once``, so the
+    overflow (1, p))`` — the layout psort assembles its answer from, so the
     fault driver's exclude-and-rescale loop composes unchanged.  Raises
     :class:`comm.PEFailure` at trace time under a matching fault plan.
 

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import comm
-from repro.core.api import SortConfig, _sort_body, trace_collectives
+from repro.core.api import SortConfig, _plan, _sim_runner, trace_collectives
 
 PP = 8
 CONTIG = [[0, 1, 2, 3], [4, 5, 6, 7]]
@@ -104,16 +104,16 @@ def test_rams_forced_ring_bitwise_equal():
     """A full two-level RAMS sort under the forced-ring chunked collectives
     must be bit-identical to the one-shot sim path."""
     p, per = 8, 16
-    body = _sort_body("sort", p, "rams", 2 * per, 2 * per,
-                      (("levels", 2),))
+    plan = _plan((p * per,), SortConfig(p=p, algorithm="rams", backend="sim",
+                                        levels=2))
+    assert plan.capacity == 2 * per and plan.algo_kw == (("levels", 2),)
     r = np.random.default_rng(0)
     keys2d = jnp.asarray(r.integers(0, 2**32, size=(p, per), dtype=np.uint64)
                          .astype(np.uint32))
     counts = jnp.full((p,), per, jnp.int32)
-    default = jax.jit(comm.sim_map(body, "sort", p))(keys2d, counts)
-    forced = jax.jit(comm.sim_map(
-        body, "sort", p,
-        impl=comm.SimCollectives(chunk_bytes=0)))(keys2d, counts)
+    default = jax.jit(_sim_runner(plan))(keys2d, counts)
+    forced = jax.jit(_sim_runner(
+        plan, impl=comm.SimCollectives(chunk_bytes=0)))(keys2d, counts)
     for a, b in zip(jax.tree.leaves(default), jax.tree.leaves(forced)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

@@ -472,11 +472,13 @@ def test_rams_trace_free_of_onb_intermediates():
     intermediate stays O(cap) per PE — the old one-hot path materialized
     (2·cap, nb) = 8·16× over this test's threshold."""
     from repro.core import comm
-    from repro.core.api import _sort_body
+    from repro.core.api import SortConfig, _plan, _sim_runner
 
     P, PER, CAP = 16, 512, 1024            # levels=1 at p=16 → nb = 4·16 = 64
-    body = _sort_body(AXIS, P, "rams", CAP, CAP, (("levels", 1),))
-    runner = comm.sim_map(body, AXIS, P)
+    plan = _plan((P * PER,), SortConfig(p=P, algorithm="rams", backend="sim",
+                                        levels=1, axis=AXIS))
+    assert plan.capacity == plan.out_capacity == CAP
+    runner = _sim_runner(plan)
     keys2d = jax.ShapeDtypeStruct((P, PER), jnp.uint32)
     counts = jax.ShapeDtypeStruct((P,), jnp.int32)
     jaxpr = jax.make_jaxpr(runner)(keys2d, counts)

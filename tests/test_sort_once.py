@@ -67,13 +67,13 @@ def _p1_program(monkeypatch, n, kernels):
         seen.update(args=args, kw=kw)
         raise Stop
 
-    real = api._psort_jit
-    monkeypatch.setattr(api, "_psort_jit", spy)
+    real = api._device_program
+    monkeypatch.setattr(api, "_device_program", spy)
     keys = np.random.default_rng(n).integers(0, 2**32, n, dtype=np.uint64)
     with pytest.raises(Stop):
         psort(keys.astype(np.uint32),
               config=SortConfig(mesh=api.default_mesh(1)))
-    assert seen["args"][6] == "rquick"             # psort's pick at p = 1
+    assert seen["kw"]["plan"].algorithm == "rquick"   # psort's pick at p = 1
     return real.trace(*seen["args"], **seen["kw"])
 
 

@@ -78,10 +78,10 @@ def test_each_call_has_one_psort_span_split_into_four(layout, tmp_path):
     np.testing.assert_array_equal(np.asarray(results[0]),
                                   np.sort(keys, axis=-1))
 
-    per = -(-N // p)
-    out_cap = api._out_capacity(info["algorithm"], N, p, per,
-                                max(4, int(np.ceil(per * 2.0))))
-    padded = d * p * out_cap * 4          # the padded u32 keys (d, p, cap)
+    plan = api._plan(keys.shape, cfg)
+    assert (plan.algorithm, plan.p) == (info["algorithm"], p)
+    assert plan.capacity == 2 * (N // p)      # capacity_factor 2.0
+    padded = d * p * plan.out_capacity * 4  # the padded u32 keys (d, p, cap)
     counts = d * p * 4                    # the int32 counts (d, p)
     # without the info: keys and counts; with it also the u32 index plane
     # and the int32 overflow counts
@@ -116,13 +116,13 @@ def test_an_external_sort_carries_no_wait_pull_or_assemble(tmp_path):
 def test_the_device_program_names_its_phases():
     # RAMS at p = 4 on the shard_map path: its CommTrace tags and the
     # scoped functions reach the compiled ops' op_name metadata
-    mesh = api.default_mesh(4)
-    cfg = SortConfig(mesh=mesh, algorithm="rams")
-    keys = jnp.zeros((4, 256), jnp.uint32)
-    counts = jnp.full((4,), 256, jnp.int32)
-    text = api._psort_jit.lower(keys, counts, mesh, cfg, "sort", 4, "rams",
-                                512, 512, (),
-                                pallas=local_kernels()).compile().as_text()
+    cfg = SortConfig(mesh=api.default_mesh(4), algorithm="rams")
+    plan = api._plan((4 * 256,), cfg)
+    keys = jnp.zeros(plan.lead + (plan.per,), jnp.uint32)
+    counts = jnp.full(plan.lead, plan.per, jnp.int32)
+    text = api._device_program.lower(
+        keys, counts, plan=plan,
+        pallas=local_kernels()).compile().as_text()
     scopes = {part for name in re.findall(r'op_name="([^"]*)"', text)
               for part in name.split("/")}
     for scope in ("shuffle", "level0", "alltoall_route", "local_sort",

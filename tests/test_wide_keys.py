@@ -13,8 +13,8 @@ import numpy as np
 import pytest
 
 from repro.core import comm
-from repro.core.api import (SortConfig, _psort_jit, _sort_body, default_mesh,
-                            psort)
+from repro.core.api import (SortConfig, _device_program, _plan, _sim_runner,
+                            default_mesh, psort)
 from repro.core.selection import select_algorithm
 from repro.core.types import local_kernels
 from repro.data.distributions import INSTANCES, generate_instance
@@ -122,11 +122,11 @@ WIDE_WIRE_BYTES = SHUFFLE_BYTES + LEVEL0_BYTES            # 38,001,552
 
 
 def _counted(dtype, n=1 << 20, p=4):
-    per = n // p
-    body = _sort_body("sort", p, "rams", 2 * per, 2 * per, ())
+    plan = _plan((n,), SortConfig(p=p, algorithm="rams", backend="sim"))
+    assert (plan.per, plan.capacity) == (n // p, 2 * (n // p))
     counter = comm.CountingCollectives(comm.SIM)
-    runner = comm.sim_map(body, "sort", p, impl=counter)
-    jax.eval_shape(runner, jax.ShapeDtypeStruct((p, per), dtype),
+    jax.eval_shape(_sim_runner(plan, impl=counter),
+                   jax.ShapeDtypeStruct((p, plan.per), dtype),
                    jax.ShapeDtypeStruct((p,), jnp.int32))
     return counter.trace
 
@@ -148,13 +148,12 @@ def test_u64_sort_collectives_counted_per_phase():
 
 
 def test_u64_device_program_names_its_splitter_pick():
-    mesh = default_mesh(4)
-    cfg = SortConfig(mesh=mesh, algorithm="rams")
-    keys = jnp.zeros((4, 256), jnp.uint64)
-    counts = jnp.full((4,), 256, jnp.int32)
-    text = _psort_jit.lower(keys, counts, mesh, cfg, "sort", 4, "rams", 512,
-                            512, (), pallas=local_kernels()).compile(
-                            ).as_text()
+    plan = _plan((4 * 256,), SortConfig(mesh=default_mesh(4),
+                                        algorithm="rams"))
+    keys = jnp.zeros(plan.lead + (plan.per,), jnp.uint64)
+    counts = jnp.full(plan.lead, plan.per, jnp.int32)
+    text = _device_program.lower(keys, counts, plan=plan,
+                                 pallas=local_kernels()).compile().as_text()
     names = set(re.findall(r'op_name="([^"]*)"', text))
     assert any("level0/splitters/" in name and "sort" in name
                for name in names), sorted(names)[:20]
