@@ -138,13 +138,13 @@ def make_shard(keys: jax.Array, count=None, capacity: Optional[int] = None,
 # Local-phase kernel policy.  Two Pallas kernels cover the local hot spots:
 # the bitonic local sort (kernels/bitonic) and the fused partition-into-
 # buckets classifier (kernels/partition).  On a TPU backend both default ON
-# and compile through Mosaic (``repro.kernels.interpret_mode``); whether
-# either beats the jnp path on the chip has not been measured yet.
-# Everywhere else (CPU/sim CI) they default OFF because there they run in
-# the Pallas interpreter, which is slow; the jnp paths are the bitwise
-# oracle the kernels are diffed against.  The ``REPRO_LOCAL_KERNELS``
-# environment variable (read at trace time, so ``monkeypatch.setenv``
-# works) overrides the default:
+# and compile through Mosaic (``repro.kernels.interpret_mode``); the sort
+# kernel takes a shard only up to ``KERNEL_SORT_MAX_WORDS`` (see
+# :func:`_pallas_sortable`).  Everywhere else (CPU/sim CI) they default OFF
+# because there they run in the Pallas interpreter, which is slow; the jnp
+# paths are the bitwise oracle the kernels are diffed against.  The
+# ``REPRO_LOCAL_KERNELS`` environment variable (read at trace time, so
+# ``monkeypatch.setenv`` works) overrides the default:
 #
 #   REPRO_LOCAL_KERNELS=all | 1 | on      both kernels
 #   REPRO_LOCAL_KERNELS=none | 0 | off    neither
@@ -241,9 +241,17 @@ def set_pallas_local_sort(enabled: Optional[bool]) -> Optional[bool]:
 
 @jax.named_scope("local_sort")
 def local_sort(shard: SortShard) -> SortShard:
-    """Sort a shard's valid elements ascending (stable w.r.t. input order)."""
-    pad = shard.pad
-    keys = jnp.where(shard.valid_mask(), shard.keys, pad)
+    """Sort a shard's valid elements ascending; every payload plane travels
+    with its key and the pad words follow the valid prefix.
+
+    By default this is one stable XLA sort of (keys, *payloads) on the key:
+    equal keys keep their input order, and a real key equal to the pad word
+    stays ahead of the pads.  Under the ``sort`` kernel policy (the TPU
+    default) the Pallas bitonic network sorts instead where it can: 4-byte
+    keys, at most one 1-D 4-byte payload, at most ``KERNEL_SORT_MAX_WORDS``
+    words.  It is not stable, so equal keys may come out with their
+    payloads in another order."""
+    keys = jnp.where(shard.valid_mask(), shard.keys, shard.pad)
     if use_pallas_local_sort() and _pallas_sortable(shard):
         from repro.kernels.bitonic import local_sort_fast
         if not shard.vals:
@@ -251,16 +259,38 @@ def local_sort(shard: SortShard) -> SortShard:
         (vname, vals), = shard.vals.items()
         ks, vs = local_sort_fast(keys, vals)
         return shard.replace(keys=ks, vals={vname: vs})
-    if not shard.vals:
-        return shard.replace(keys=jnp.sort(keys))
-    order = jnp.argsort(keys, stable=True)
-    return shard.replace(keys=keys[order],
-                         vals={k: v[order] for k, v in shard.vals.items()})
+    # 1-D payloads are operands of the sort itself; a wider one (rows per
+    # element) is gathered by the sorted position of a carried iota.
+    flat = [k for k, v in shard.vals.items() if v.ndim == 1]
+    wide = [k for k in shard.vals if k not in flat]
+    carried = [shard.vals[k] for k in flat]
+    if wide:
+        carried.append(jnp.arange(shard.capacity, dtype=jnp.int32))
+    out = jax.lax.sort((keys, *carried), num_keys=1, is_stable=True)
+    vals = dict(zip(flat, out[1:]))
+    vals.update({k: shard.vals[k][out[-1]] for k in wide})
+    return shard.replace(keys=out[0], vals=vals)
+
+
+# The largest shard the bitonic kernel sorts; above it, XLA's sort.  On one
+# TPU v5e, alone, XLA's stable sort of (key, u32 payload) beats the kernel
+# at every size measured (median of 25 warm calls: 2^17 words 1.01 ms
+# against 1.29; 1,057,292 words 3.46 against 10.2; 2,109,464 words 6.85
+# against 21.4; 2^25 words 115.5 against 386.1; tools/local_sort_sweep.py).
+# Inside psort the picture differs.  At 2^25 words (one chip, n = 2^24)
+# XLA's sort cut the device time of a call from 391 to 121 ms.  But the
+# four-chip RAMS program at n = 2^20, whose sorts are 1,057,292 and
+# 2,109,464 words, got slower with it: a 90th-percentile call of 152.2 ms
+# against 135.8 with the kernel.  So the kernel keeps the sizes it pads to
+# 2^22 words or fewer, the largest at which it was measured ahead.
+KERNEL_SORT_MAX_WORDS = 1 << 22
 
 
 def _pallas_sortable(shard: SortShard) -> bool:
     from repro.kernels.bitonic import supported
     if not supported(shard.capacity, shard.keys.dtype):
+        return False
+    if shard.capacity > KERNEL_SORT_MAX_WORDS:
         return False
     if len(shard.vals) > 1:
         return False

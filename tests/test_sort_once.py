@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import SortConfig, api, comm, psort
+from repro.core import SortConfig, api, comm, psort, types
 from repro.core.types import make_shard
 
 ALGORITHMS = ("rquick", "ntb-quick", "rfis", "rams", "ntb-ams", "bitonic",
@@ -112,3 +112,39 @@ def test_p1_program_launches_one_sort_blocks(monkeypatch):
     block = np.prod([getattr(b, "block_size", b)
                      for b in gm.block_mappings[0].block_shape])
     assert gm.grid[0] * block == 2 * n
+
+
+@pytest.mark.parametrize("n,kernel", [(1 << 14, True), (1 << 22, False)],
+                         ids=["kernel-below-cap", "xla-above-cap"])
+def test_p1_program_local_sort_at_tpu_default(monkeypatch, n, kernel):
+    """Under a TPU backend's default policy the p = 1 program sorts its
+    2·n-word shard with the bitonic kernel up to ``KERNEL_SORT_MAX_WORDS``
+    and above it with one stable XLA sort that carries the index plane as
+    an operand: no gather after it and no kernel launch."""
+    assert (2 * n <= types.KERNEL_SORT_MAX_WORDS) == kernel
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        policy = types._default_local_kernels()
+    assert policy.sort
+    prev = types.set_local_kernels(policy)
+    try:
+        traced = _p1_program(monkeypatch, n, "auto")
+    finally:
+        types.set_local_kernels(prev)
+    eqns = list(_eqns(traced.jaxpr.jaxpr))
+    launches = [e for e in eqns if e.params.get("name") == "sort_blocks"]
+    sorts = [e for e in eqns if e.primitive.name == "sort"]
+    if kernel:
+        assert len(launches) == 1 and not sorts
+        return
+    assert not launches
+    assert not [e for e in eqns if e.primitive.name in ("gather",
+                                                        "pallas_call")]
+    sort, = sorts
+    assert [(v.aval.shape, v.aval.dtype) for v in sort.invars] == \
+        [((2 * n,), jnp.uint32)] * 2                  # (keys, idx)
+    assert sort.params["num_keys"] == 1 and sort.params["is_stable"]
+    text = traced.lower().as_text()
+    assert text.count("stablehlo.sort") == 1
+    assert (f"}}) : (tensor<{2 * n}xui32>, tensor<{2 * n}xui32>) -> "
+            in text)
